@@ -1,6 +1,7 @@
 """Roofline analysis from the dry-run artifacts (deliverable g).
 
-Three terms per (arch x shape x mesh), TPU v5e constants:
+Three terms per (arch x shape x mesh), TPU v5e constants
+(``kernels/hw_constants.V5E``; the dry run compiles the bf16 step):
 
     compute    = dot_FLOPs        / (chips * 197e12 FLOP/s)
     memory     = hbm_bytes        / (chips * 819e9  B/s)
@@ -27,7 +28,7 @@ ROOT = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(ROOT / "src"))
 
 # per-chip ceilings — single source of truth, drift-tested
-from repro.kernels.hw_constants import HBM_BW, ICI_BW, ICI_LINKS, PEAK_FLOPS  # noqa: E402
+from repro.kernels.hw_constants import V5E as CHIP  # noqa: E402
 RESULTS = ROOT / "results" / "dryrun"
 OUT = ROOT / "results" / "roofline"
 
@@ -48,9 +49,9 @@ def analyze_record(rec: dict, cfg, shape):
     flops_dev = hc["dot_flops"]
     bytes_dev = hc["hbm_bytes"]
     coll_dev = hc["collective_bytes"]
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = bytes_dev / HBM_BW
-    t_coll = coll_dev / (ICI_LINKS * ICI_BW)
+    t_compute = flops_dev / CHIP.peak_bf16_flops
+    t_memory = bytes_dev / CHIP.hbm_bw
+    t_coll = coll_dev / (CHIP.ici_links * CHIP.ici_bw)
     dominant = max((("compute", t_compute), ("memory", t_memory),
                     ("collective", t_coll)), key=lambda kv: kv[1])[0]
     mf = model_flops(cfg, shape)
@@ -67,7 +68,7 @@ def analyze_record(rec: dict, cfg, shape):
         "useful_ratio": mf / hlo_total if hlo_total else 0.0,
         "roofline_bound_s": bound,
         "compute_fraction": t_compute / max(bound, 1e-30),
-        "mfu_bound": mf / (chips * PEAK_FLOPS) / max(bound, 1e-30),
+        "mfu_bound": mf / (chips * CHIP.peak_bf16_flops) / max(bound, 1e-30),
         "hbm_gb_per_dev": (rec["memory"]["temp_bytes"]
                            + rec["memory"]["argument_bytes"]) / 2**30,
         "collective_breakdown": {k: v["bytes"] for k, v in

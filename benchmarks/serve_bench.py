@@ -1429,6 +1429,8 @@ def main():
             args.rate = max(args.rate, 1.0)
             args.convs = min(args.convs, 3)
             args.turns = min(args.turns, 3)
+    from repro.launch.compile_cache import configure_compile_cache
+    configure_compile_cache()
     raise SystemExit(bench(args))
 
 

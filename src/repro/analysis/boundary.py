@@ -10,7 +10,7 @@ recognize and exempt from the pool-scale-cast rule (while still auditing
 everything around them).
 
 ``kernel_boundary`` wraps a function in a non-inlined ``jax.jit`` so it
-shows up as a ``pjit`` eqn carrying the function's name, and records that
+shows up as a ``jit`` eqn carrying the function's name, and records that
 name here.  ``repro.analysis.jaxpr_audit`` treats eqn scopes whose name is
 registered as kernel interiors.
 
@@ -34,7 +34,7 @@ def kernel_boundary(*, why: str, static_argnums=()) -> Callable:
     """Decorator: mark ``fn`` as a kernel-equivalent scope.
 
     Wraps ``fn`` in ``jax.jit(..., inline=False)`` so that when traced
-    inside an outer jit it appears as a named ``pjit`` eqn, and registers
+    inside an outer jit it appears as a named ``jit`` eqn, and registers
     the name for the auditor.  Numerics are unchanged; under an outer jit
     the XLA inliner still fuses the body after lowering.
     """

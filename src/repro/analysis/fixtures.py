@@ -122,7 +122,7 @@ def _dead_unclamped_decode_map(_bkv):
 
 def _trash_paged_map(_psize):
     def kv_map(bb, h, k, lens, btab):    # noqa: ARG001
-        return (btab[bb, k], 0, h, 0)    # dead k reads the trash page
+        return (btab[bb, k], h, 0, 0)    # dead k reads the trash page
     return kv_map
 
 

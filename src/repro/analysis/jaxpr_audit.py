@@ -36,7 +36,7 @@ broke the contract.  This module can.  Rules, each with a stable id:
 
 ``DONATION``
     Every cache pool leaf must appear donated (``donated_invars``) on the
-    hot graph's pjit eqn — a dropped donation doubles pool HBM.
+    hot graph's jit eqn — a dropped donation doubles pool HBM.
 
 ``DONATION-ALIAS``
     No two live cache leaves may share a device buffer: XLA refuses (or
@@ -54,11 +54,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 import jax
 
-try:    # jax >= 0.6 moved the IR types out of jax.core
-    from jax.extend import core as jcore
-    _ = jcore.Jaxpr, jcore.ClosedJaxpr
-except (ImportError, AttributeError):    # jax 0.4.x floor
-    from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.analysis import boundary as boundary_mod
 
@@ -129,7 +125,7 @@ def _sub_jaxprs(eqn) -> List[Tuple[str, jcore.Jaxpr, Optional[List[bool]]]]:
     explicit per-sub-invar taint seed (conservative where unknown)."""
     prim, params = eqn.primitive.name, eqn.params
     subs: List[Tuple[str, jcore.Jaxpr, Optional[List[bool]]]] = []
-    if prim in ("pjit", "closed_call", "custom_jvp_call", "custom_vjp_call",
+    if prim in ("jit", "closed_call", "custom_jvp_call", "custom_vjp_call",
                 "remat", "checkpoint", "shard_map"):
         j = (params.get("jaxpr") or params.get("call_jaxpr")
              or params.get("fun_jaxpr"))
@@ -157,6 +153,24 @@ def _as_open(j) -> jcore.Jaxpr:
     return j.jaxpr if isinstance(j, jcore.ClosedJaxpr) else j
 
 
+def pallas_calls(closed) -> List:
+    """Every ``pallas_call`` eqn reachable from a traced graph (kernel
+    bodies are not descended into).  Their ``interpret`` param says
+    whether the kernel would run compiled or through the interpreter."""
+    found: List = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                found.append(eqn)
+                continue
+            for _, sub, _ in _sub_jaxprs(eqn):
+                walk(sub)
+
+    walk(_as_open(closed))
+    return found
+
+
 def audit_graph(fn, args, *, graph: str, pool_threshold: int,
                 boundaries: Optional[Dict[str, str]] = None,
                 check_donation: bool = True,
@@ -166,7 +180,7 @@ def audit_graph(fn, args, *, graph: str, pool_threshold: int,
     ``pool_threshold`` is the element count above which an int->float
     convert counts as pool-scale; ``donate_argnums`` names the positional
     args whose leaves must be donated (the cache), checked against the
-    traced pjit eqn's ``donated_invars``."""
+    traced jit eqn's ``donated_invars``."""
     if boundaries is None:
         boundaries = dict(boundary_mod.REGISTRY)
     closed = jax.make_jaxpr(fn)(*args)
@@ -263,18 +277,18 @@ def audit_graph(fn, args, *, graph: str, pool_threshold: int,
 
 def _audit_donation(closed, args, *, graph: str,
                     donate_argnums: Tuple[int, ...]) -> List[Violation]:
-    """The traced fn is jitted, so the outer jaxpr is a single pjit eqn
+    """The traced fn is jitted, so the outer jaxpr is a single jit eqn
     whose ``donated_invars`` must cover every leaf of the donated args."""
     out: List[Violation] = []
-    pjit_eqns = [e for e in closed.jaxpr.eqns if e.primitive.name == "pjit"]
-    if not pjit_eqns:
+    jit_eqns = [e for e in closed.jaxpr.eqns if e.primitive.name == "jit"]
+    if not jit_eqns:
         return [Violation("DONATION", graph, "",
-                          "no pjit eqn found — hot graph is not jitted")]
-    eqn = pjit_eqns[0]
+                          "no jit eqn found — hot graph is not jitted")]
+    eqn = jit_eqns[0]
     donated = eqn.params.get("donated_invars")
     if donated is None:
         return [Violation("DONATION", graph, "",
-                          "pjit eqn carries no donated_invars")]
+                          "jit eqn carries no donated_invars")]
     # flat positions of each positional arg's leaves
     sizes = [len(jax.tree_util.tree_leaves(a)) for a in args]
     offsets = [sum(sizes[:i]) for i in range(len(sizes))]
@@ -299,11 +313,7 @@ def audit_cache_aliasing(cache, *, graph: str = "cache") -> List[Violation]:
     Checked on the LIVE pytree — jaxpr tracing cannot see value aliasing."""
     out: List[Violation] = []
     seen: Dict[Tuple, str] = {}
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(cache) \
-        if hasattr(jax.tree_util, "tree_flatten_with_path") else (None, None)
-    if leaves is None:    # very old jax fallback
-        leaves = [((i,), l) for i, l in
-                  enumerate(jax.tree_util.tree_leaves(cache))]
+    leaves, _ = jax.tree_util.tree_flatten_with_path(cache)
     for path, leaf in leaves:
         if not isinstance(leaf, jax.Array):
             continue

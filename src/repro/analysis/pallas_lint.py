@@ -49,7 +49,9 @@ from repro.analysis.jaxpr_audit import Violation
 from repro.kernels import autotune
 from repro.kernels import decode_attention as DA
 from repro.kernels import prefill_attention as PA
-from repro.kernels.hw_constants import VMEM_BUDGET, VMEM_FILL
+from repro.kernels.hw_constants import V5E, VMEM_FILL
+
+VMEM_BUDGET = V5E.vmem_budget    # the kernels' target chip
 
 LUT_BYTES = 512 * 4          # exp LUT tile (LUT_SIZE int32 in VMEM)
 
@@ -131,7 +133,7 @@ def check_paged_decode_kv_map(map_factory: Callable = DA.paged_kv_index_map,
         lens = np.full((b,), lens_val, np.int32)
         last_live = max((lens_val - 1) // psize, 0)
         for bb, h, k in itertools.product(range(b), range(hkv), range(nb)):
-            pg, r0, h_o, r1 = (int(x) for x in kv_map(bb, h, k, lens, btab))
+            pg, h_o, r0, r1 = (int(x) for x in kv_map(bb, h, k, lens, btab))
             if not (0 <= pg < n_pages and r0 == 0 and h_o == h and r1 == 0):
                 out.append(_violation(
                     "IDXMAP-RANGE", kernel,
@@ -173,7 +175,7 @@ def check_prefill_kv_map(map_factory: Callable = PA.prefill_kv_index_map,
         for bb, hh, qi, ki in itertools.product(
                 range(b), range(h), range(nq), range(nb)):
             frontier = (pos0_val + (qi + 1) * bq - 1) // psize
-            pg, r0, h_o, r1 = (int(x)
+            pg, h_o, r0, r1 = (int(x)
                                for x in kv_map(bb, hh, qi, ki, pos0, btab))
             if not (0 <= pg < n_pages and r0 == 0 and r1 == 0
                     and 0 <= h_o < hkv):

@@ -139,8 +139,8 @@ def dequantize_kv_page(packed_u8: jax.Array, scale: jax.Array,
                      "oracles; the Pallas kernels do this per tile in VMEM")
 def dequantize_kv_pool(packed_pool_u8: jax.Array,
                        page_scales: jax.Array) -> jax.Array:
-    """Whole-pool dequant: (n_pages, P, Hkv, hd//2) uint8 + (n_pages,) fp32
-    -> (n_pages, P, Hkv, hd) int8.  Used by the jnp fallback paths and the
+    """Whole-pool dequant: (n_pages, Hkv, P, hd//2) uint8 + (n_pages,) fp32
+    -> (n_pages, Hkv, P, hd) int8.  Used by the jnp fallback paths and the
     kernel oracles — NOT by the Pallas kernels, which dequantize per tile
     in VMEM and never materialize this view.  Registered as a kernel
     boundary: the pool-scale float cast inside is the audited exemption."""

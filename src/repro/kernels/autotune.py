@@ -16,8 +16,9 @@ the paged kernels' dead-block clamping makes their outputs tile-size
 independent (see the kernel docstrings) — NEVER change numerics: autotune
 moves DMA/grid overhead around, not bits.
 
-The cost model mirrors ``benchmarks/roofline.py``'s v4-lite ceilings.  A
-grid step costs ``max(tile_bytes / HBM_BW, tile_flops / PEAK_INT8)`` plus a
+The cost model prices the chip the kernels run on (``hw_constants``, keyed
+by device kind; off the TPU the kernels' target, v5e).  A grid step costs
+``max(tile_bytes / hbm_bw, tile_flops / peak_int8_ops)`` plus a
 fixed per-step overhead (DMA issue + grid bookkeeping); fewer, larger steps
 amortize the overhead until the double-buffered tiles overflow the VMEM
 budget.  For prefill, every KV page is streamed once per (head, q-block),
@@ -42,15 +43,11 @@ from __future__ import annotations
 
 import os
 
-# v4-lite ceilings — shared with benchmarks/roofline.py and the analysis
-# lane's VMEM lint via kernels/hw_constants (drift-tested).
-from repro.kernels.hw_constants import (  # noqa: F401  (re-exported names)
-    HBM_BW,
-    PEAK_INT8_FLOPS,
-    STEP_OVERHEAD_S,
-    VMEM_BUDGET,
-    VMEM_FILL,
-)
+import jax
+
+# chip ceilings — shared with benchmarks/roofline.py and the analysis
+# lane's VMEM lint via kernels/hw_constants (drift-tested)
+from repro.kernels import hw_constants as HW
 
 DECODE_BKV_CANDIDATES = (128, 256, 512, 1024)
 # 8/16 exist for the small ragged batches the speculative verify forward
@@ -67,6 +64,14 @@ _cache: dict = {}
 
 def clear_cache() -> None:
     _cache.clear()
+
+
+def target_chip() -> HW.Chip:
+    """Ceilings of the chip the kernels run on.  Off the TPU they run in
+    interpret mode (or as jnp oracles) and still target a v5e, so that
+    entry is named explicitly there."""
+    dev = jax.devices()[0]
+    return HW.chip(dev.device_kind) if dev.platform == "tpu" else HW.V5E
 
 
 def _mode() -> str:
@@ -160,15 +165,16 @@ def prefill_bq(sq: int, *, batch_slots: int, page_size: int, hkv: int,
 def _roofline_pick(candidates, n, *, tile_bytes, tile_flops, steps,
                    extra_vmem=lambda c: 0) -> int:
     """Pick the candidate minimizing modeled wall time within VMEM budget."""
+    hw = target_chip()
     best, best_t = None, None
     for raw in candidates:
         c = _fit(raw, n)
         # double-buffered in/out tiles must fit the fill fraction of VMEM
-        if 2 * tile_bytes(c) + extra_vmem(c) > VMEM_BUDGET * VMEM_FILL:
+        if 2 * tile_bytes(c) + extra_vmem(c) > hw.vmem_budget * HW.VMEM_FILL:
             continue
-        t = steps(c) * (STEP_OVERHEAD_S +
-                        max(tile_bytes(c) / HBM_BW,
-                            tile_flops(c) / PEAK_INT8_FLOPS))
+        t = steps(c) * (HW.STEP_OVERHEAD_S +
+                        max(tile_bytes(c) / hw.hbm_bw,
+                            tile_flops(c) / hw.peak_int8_ops))
         if best_t is None or t < best_t or (t == best_t and c > best):
             best, best_t = c, t
     if best is None:                      # every candidate overflowed VMEM
@@ -285,7 +291,7 @@ def _measured_prefill_bq(key, sq, *, batch_slots, page_size, hkv, hd,
     meta = _attn_quant_meta()
     if kv_bits == 4:
         pool = lambda: jnp.asarray(
-            rng.integers(0, 256, (n_pages, page_size, hkv, hd // 2)),
+            rng.integers(0, 256, (n_pages, hkv, page_size, hd // 2)),
             jnp.uint8)
         kp, vp = pool(), pool()
         scale = jnp.full((n_pages,), 0.05, jnp.float32)
@@ -293,7 +299,7 @@ def _measured_prefill_bq(key, sq, *, batch_slots, page_size, hkv, hd,
             q, kp, vp, scale, scale, btab, pos0, *meta, bq=c)
     else:
         pool = lambda: jnp.asarray(
-            rng.integers(-64, 65, (n_pages, page_size, hkv, hd)), jnp.int8)
+            rng.integers(-64, 65, (n_pages, hkv, page_size, hd)), jnp.int8)
         kp, vp = pool(), pool()
         run = lambda c: ops.paged_prefill_attention_q(
             q, kp, vp, btab, pos0, *meta, bq=c)

@@ -34,14 +34,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import fixedpoint as fxp
 from repro.core.qsoftmax import LUT_SIZE, MASK_OFFSET
-from repro.kernels.pallas_compat import CompilerParams, divisor_tile
+from repro.kernels.tiling import divisor_tile
 from repro.kernels.quant_softmax import lut_lookup
 
 NEG_INIT = -(1 << 30)
 
 
 def _kv_load_i8(k_ref, v_ref, _b_i, _k_i):
-    """Default KV tile loader: the pool already holds int8 codes."""
+    """Default KV tile loader: the cache already holds int8 codes."""
+    return k_ref[0, 0], v_ref[0, 0]
+
+
+def _kv_load_i8_seq_major(k_ref, v_ref, _b_i, _k_i):
+    """KV tile loader for the contiguous (B, Smax, Hkv, D) cache."""
     return k_ref[0, :, 0], v_ref[0, :, 0]
 
 
@@ -60,14 +65,15 @@ def decode_kv_index_map(bkv):
 
 def paged_kv_index_map(psize):
     """KV BlockSpec index map shared by BOTH paged decode kernels (int8 and
-    int4-packed): clamp the dead logical block to the last live one, THEN
-    translate through the slot's scalar-prefetched block-table row.  One
-    factory — not two copies — so the int8/q4 agreement is structural and
-    ``pallas_lint`` can prove the returned page index stays inside the
-    pool for every grid point."""
+    int4-packed) over the head-major (n_pages, Hkv, P, D) pool: clamp the
+    dead logical block to the last live one, THEN translate through the
+    slot's scalar-prefetched block-table row.  One factory — not two
+    copies — so the int8/q4 agreement is structural and ``pallas_lint`` can
+    prove the returned page index stays inside the pool for every grid
+    point."""
     def kv_map(bb, h, k, lens, btab):
         last_live = jnp.maximum((lens[bb] - 1) // psize, 0)
-        return (btab[bb, jnp.minimum(k, last_live)], 0, h, 0)
+        return (btab[bb, jnp.minimum(k, last_live)], h, 0, 0)
     return kv_map
 
 
@@ -141,7 +147,7 @@ def _decode_body(g, bkv, kv_load, len_ref, q_ref, k_ref, v_ref, lut_ref,
 
 
 def _decode_kernel(g, bkv, len_ref, *rest):
-    _decode_body(g, bkv, _kv_load_i8, len_ref, *rest)
+    _decode_body(g, bkv, _kv_load_i8_seq_major, len_ref, *rest)
 
 
 @functools.partial(jax.jit, static_argnames=("bkv", "interpret"))
@@ -191,7 +197,7 @@ def decode_qattention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), jnp.int8),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -206,14 +212,14 @@ def decode_qattention(
 def _paged_decode_kernel(g, psize, len_ref, _btab_ref, *rest):
     # the block table feeds only the BlockSpec index maps (which pool page
     # backs this slot's k-th logical KV block); the body is exactly the
-    # contiguous kernel with block size = page size
-    _decode_kernel(g, psize, len_ref, *rest)
+    # contiguous kernel's with block size = page size
+    _decode_body(g, psize, _kv_load_i8, len_ref, *rest)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_qattention(
     q_i8: jax.Array,          # int8 (B, Hkv, G, D) — one token/slot, grouped q
-    k_pool: jax.Array,        # int8 (n_pages, P, Hkv, D) — global page pool
+    k_pool: jax.Array,        # int8 (n_pages, Hkv, P, D) — global page pool
     v_pool: jax.Array,
     block_tables: jax.Array,  # int32 (B, max_blocks): slot -> pool pages
     lengths: jax.Array,       # int32 (B,): valid rows per slot
@@ -223,6 +229,8 @@ def paged_decode_qattention(
     """Paged continuous-batching decode attention: the KV BlockSpec index
     map follows the slot's scalar-prefetched block-table entry instead of a
     linear offset, so one grid step streams one *pool page* per kv head.
+    The pool is head-major, so that (P, D) tile is the array's own minor
+    pair and the block shape satisfies the TPU's (8, 128) tiling rule.
 
     Same clamping machinery as the contiguous kernel: grid steps past a
     slot's length re-address the slot's last live page — already resident
@@ -230,7 +238,7 @@ def paged_decode_qattention(
     traffic for table entries beyond their chain.  One logical KV block ==
     one page, so the grid tiles exactly (no divisor fallback needed)."""
     b, hkv, g, d = q_i8.shape
-    psize = k_pool.shape[1]
+    psize = k_pool.shape[2]
     nb = block_tables.shape[1]
     grid = (b, hkv, nb)
     kernel = functools.partial(_paged_decode_kernel, g, psize)
@@ -242,8 +250,8 @@ def paged_decode_qattention(
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
                          lambda bb, h, k, lens, btab: (bb, h, 0, 0)),
-            pl.BlockSpec((1, psize, 1, d), kv_map),
-            pl.BlockSpec((1, psize, 1, d), kv_map),
+            pl.BlockSpec((1, 1, psize, d), kv_map),
+            pl.BlockSpec((1, 1, psize, d), kv_map),
             pl.BlockSpec((LUT_SIZE,), lambda bb, h, k, lens, btab: (0,)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -262,7 +270,7 @@ def paged_decode_qattention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), jnp.int8),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -284,8 +292,8 @@ def _paged_decode_q4_kernel(g, psize, len_ref, btab_ref, q_ref, k_ref, v_ref,
     # exactly page btab[b, k], so scale and payload always agree)
     def load(kr, vr, b_i, k_i):
         pg = btab_ref[b_i, k_i]
-        return (dequant_kv_tile(kr[0, :, 0], ks_ref[pg]),
-                dequant_kv_tile(vr[0, :, 0], vs_ref[pg]))
+        return (dequant_kv_tile(kr[0, 0], ks_ref[pg]),
+                dequant_kv_tile(vr[0, 0], vs_ref[pg]))
 
     _decode_body(g, psize, load, len_ref, q_ref, k_ref, v_ref, lut_ref,
                  mi_ref, si_ref, inv_ref, osc_ref, o_ref, m_scr, den_scr,
@@ -295,7 +303,7 @@ def _paged_decode_q4_kernel(g, psize, len_ref, btab_ref, q_ref, k_ref, v_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_decode_qattention_q4(
     q_i8: jax.Array,          # int8 (B, Hkv, G, D) — one token/slot, grouped q
-    k_pool: jax.Array,        # uint8 (n_pages, P, Hkv, D//2) — packed pool
+    k_pool: jax.Array,        # uint8 (n_pages, Hkv, P, D//2) — packed pool
     v_pool: jax.Array,
     k_scale: jax.Array,       # fp32 (n_pages,): shared dequant scale per page
     v_scale: jax.Array,
@@ -312,7 +320,7 @@ def paged_decode_qattention_q4(
     weights; no dequantized KV view ever materializes in HBM.  Bit-exact
     vs ``ref.py::paged_decode_qattention_q4_ref``."""
     b, hkv, g, d = q_i8.shape
-    psize = k_pool.shape[1]
+    psize = k_pool.shape[2]
     dp = k_pool.shape[3]                          # D//2 packed bytes
     assert dp * 2 == d, (dp, d)
     nb = block_tables.shape[1]
@@ -326,8 +334,8 @@ def paged_decode_qattention_q4(
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
                          lambda bb, h, k, lens, btab: (bb, h, 0, 0)),
-            pl.BlockSpec((1, psize, 1, dp), kv_map),
-            pl.BlockSpec((1, psize, 1, dp), kv_map),
+            pl.BlockSpec((1, 1, psize, dp), kv_map),
+            pl.BlockSpec((1, 1, psize, dp), kv_map),
             pl.BlockSpec((LUT_SIZE,), lambda bb, h, k, lens, btab: (0,)),
             pl.BlockSpec(memory_space=pltpu.SMEM),    # k page scales
             pl.BlockSpec(memory_space=pltpu.SMEM),    # v page scales
@@ -348,7 +356,7 @@ def paged_decode_qattention_q4(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), jnp.int8),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

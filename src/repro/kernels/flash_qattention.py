@@ -24,8 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 from repro.core import fixedpoint as fxp
 from repro.core.qsoftmax import LUT_SIZE, MASK_OFFSET
 from repro.kernels.quant_softmax import lut_lookup
@@ -165,7 +163,7 @@ def flash_qdecode(
             pltpu.VMEM((g, 128), jnp.float32),
             pltpu.VMEM((g, d), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -280,7 +278,7 @@ def flash_qattention(
             pltpu.VMEM((bq, 128), jnp.float32),  # running denominator
             pltpu.VMEM((bq, d), jnp.float32),    # output accumulator
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

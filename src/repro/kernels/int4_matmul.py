@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 from repro.core import fixedpoint as fxp
 
 # Default MXU-aligned tile sizes (v5e: 128x128 MXU, ~16 MB VMEM/core).
@@ -99,18 +97,19 @@ def int4_matmul(
             # x column blocks [K/2, K) — pair row r's HIGH nibbles
             pl.BlockSpec((bm, bk2), lambda i, j, t, nk=nk: (i, t + nk)),
             pl.BlockSpec((bk2, bn), lambda i, j, t: (t, j)),
-            pl.BlockSpec((bn,), lambda i, j, t: (j,)),
+            # bias as a (1, N) row: a 1-D block of it is refused on TPU
+            pl.BlockSpec((1, bn), lambda i, j, t: (0, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x_i8, x_i8, w_packed, bias_i32,
+    )(x_i8, x_i8, w_packed, bias_i32.reshape(1, n),
       jnp.asarray(M_q, jnp.int32).reshape(1), jnp.asarray(shift_q, jnp.int32).reshape(1))
 
 
@@ -162,16 +161,16 @@ def int8_bitsplit_matmul(
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, t: (i, t)),
             pl.BlockSpec((bk, bn), lambda i, j, t: (t, j)),
-            pl.BlockSpec((bn,), lambda i, j, t: (j,)),
+            pl.BlockSpec((1, bn), lambda i, j, t: (0, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x_i8, w_i8, bias_i32,
+    )(x_i8, w_i8, bias_i32.reshape(1, n),
       jnp.asarray(M_q, jnp.int32).reshape(1), jnp.asarray(shift_q, jnp.int32).reshape(1))

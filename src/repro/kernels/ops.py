@@ -46,19 +46,24 @@ def _pad_rows(x: jax.Array, mult: int):
 
 
 def linear_w4a8(x_i8: jax.Array, f: FoldedLinear, *, impl: Optional[str] = None):
-    """y_i8 = requant(x_i8 @ unpack(W4) + b).  x: int8 (..., K) -> (..., N)."""
+    """y_i8 = requant(x_i8 @ W + b).  x: int8 (..., K) -> (..., N).
+
+    ``f.w_bits`` picks the weight datapath: 4 = nibble-packed int4 codes,
+    8 = full int8 codes through the BIM Type-A bit-split kernel."""
+    assert f.w_bits in (4, 8), f.w_bits
     lead = x_i8.shape[:-1]
     k = x_i8.shape[-1]
     x2 = x_i8.reshape(-1, k)
     b = backend(impl)
-    if b == "ref" or f.w_bits != 4:
+    if b == "ref":
         ref_mm = (_ref.int4_matmul_ref if f.w_bits == 4 else
                   _ref.int8_bitsplit_matmul_ref)
         y = ref_mm(x2, f.w_packed, f.bias_i, f.M, f.shift)
         return y.reshape(*lead, -1)
+    mm = _mm.int4_matmul if f.w_bits == 4 else _mm.int8_bitsplit_matmul
     x2p, m = _pad_rows(x2, 8)
-    y = _mm.int4_matmul(x2p, f.w_packed, f.bias_i, f.M, f.shift,
-                        interpret=(b == "interpret"))
+    y = mm(x2p, f.w_packed, f.bias_i, f.M, f.shift,
+           interpret=(b == "interpret"))
     return y[:m].reshape(*lead, -1)
 
 
@@ -140,7 +145,7 @@ def paged_decode_attention_q(
 ):
     """Paged continuous-batching decode attention.
 
-    (B, Hkv, G, D) grouped queries x (n_pages, P, Hkv, D) global int8 page
+    (B, Hkv, G, D) grouped queries x (n_pages, Hkv, P, D) global int8 page
     pool, addressed per slot through a (B, max_blocks) block table ->
     (B, Hkv, G, D) int8 context.  ref backend = the block-online oracle
     (kernel-exact accumulation order); pallas = the scalar-prefetch paged
@@ -169,7 +174,7 @@ def paged_decode_attention_q4(
     """Paged decode attention over the int4-PACKED page pool.
 
     Same contract as ``paged_decode_attention_q`` but the pool leaves are
-    (n_pages, P, Hkv, D//2) uint8 nibble-planar with (n_pages,) fp32 shared
+    (n_pages, Hkv, P, D//2) uint8 nibble-planar with (n_pages,) fp32 shared
     scales per page; the pallas backend fuses dequant into the kernel's
     inner loop (half the HBM bytes per page), the ref backend dequantizes
     the whole pool and runs the int8 oracle — bit-exact either way."""
@@ -191,8 +196,8 @@ def paged_prefill_attention_q(
 ):
     """Paged chunked-prefill attention.
 
-    (B, H, S, D) chunk queries at positions [pos0, pos0+S) x (n_pages, P,
-    Hkv, D) global int8 page pool, addressed per slot through a
+    (B, H, S, D) chunk queries at positions [pos0, pos0+S) x (n_pages, Hkv,
+    P, D) global int8 page pool, addressed per slot through a
     (B, max_blocks) block table -> (B, H, S, D) int8 context over each
     slot's whole mapped chain.  ref backend = the block-online oracle
     (kernel-exact accumulation order); pallas = the block-table-walking
@@ -222,8 +227,8 @@ def _autotuned_bq(q_i8, k_pool, block_tables, *, kv_bits: int) -> int:
     from repro.kernels import autotune
     bsz, h, sq, hd = q_i8.shape
     return autotune.prefill_bq(
-        sq, batch_slots=bsz, page_size=k_pool.shape[1],
-        hkv=k_pool.shape[2], hd=hd, kv_bits=kv_bits,
+        sq, batch_slots=bsz, page_size=k_pool.shape[2],
+        hkv=k_pool.shape[1], hd=hd, kv_bits=kv_bits,
         n_blocks=block_tables.shape[1], n_heads=h)
 
 

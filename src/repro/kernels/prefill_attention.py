@@ -40,7 +40,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import fixedpoint as fxp
 from repro.core.qsoftmax import LUT_SIZE, MASK_OFFSET
-from repro.kernels.pallas_compat import CompilerParams, divisor_tile
+from repro.kernels.tiling import divisor_tile
 from repro.kernels.quant_softmax import lut_lookup
 
 NEG_INIT = -(1 << 30)
@@ -48,7 +48,7 @@ NEG_INIT = -(1 << 30)
 
 def _kv_load_i8(k_ref, v_ref, _b_i, _k_i):
     """Default int8 page load: the pool tile IS the code tile."""
-    return k_ref[0, :, 0], v_ref[0, :, 0]
+    return k_ref[0, 0], v_ref[0, 0]
 
 
 def prefill_kv_index_map(bq, psize, group):
@@ -61,7 +61,7 @@ def prefill_kv_index_map(bq, psize, group):
     the kernel's contract ``pos0 + sq <= nb * psize``)."""
     def kv_map(bb, hh, qi, ki, pos0s, btab):
         frontier = (pos0s[bb] + (qi + 1) * bq - 1) // psize
-        return (btab[bb, jnp.minimum(ki, frontier)], 0, hh // group, 0)
+        return (btab[bb, jnp.minimum(ki, frontier)], hh // group, 0, 0)
     return kv_map
 
 
@@ -127,7 +127,7 @@ def _paged_prefill_kernel(bq, psize, pos0_ref, _btab_ref, *rest):
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
 def paged_prefill_qattention(
     q_i8: jax.Array,          # int8 (B, H, S, D) — chunk queries, ungrouped
-    k_pool: jax.Array,        # int8 (n_pages, P, Hkv, D) — global page pool
+    k_pool: jax.Array,        # int8 (n_pages, Hkv, P, D) — global page pool
     v_pool: jax.Array,
     block_tables: jax.Array,  # int32 (B, max_blocks): slot -> pool pages
     pos0: jax.Array,          # int32 (B,): page-aligned chunk start per slot
@@ -140,8 +140,7 @@ def paged_prefill_qattention(
     must already be scattered into the pool (the chunk forward writes
     before it attends, so intra-chunk causality falls out of the mask)."""
     b, h, sq, d = q_i8.shape
-    psize = k_pool.shape[1]
-    hkv = k_pool.shape[2]
+    hkv, psize = k_pool.shape[1], k_pool.shape[2]
     group = h // hkv
     nb = block_tables.shape[1]
     bq = divisor_tile(bq, sq)
@@ -155,8 +154,8 @@ def paged_prefill_qattention(
         in_specs=[
             pl.BlockSpec((1, 1, bq, d),
                          lambda bb, hh, qi, ki, pos0s, btab: (bb, hh, qi, 0)),
-            pl.BlockSpec((1, psize, 1, d), kv_map),
-            pl.BlockSpec((1, psize, 1, d), kv_map),
+            pl.BlockSpec((1, 1, psize, d), kv_map),
+            pl.BlockSpec((1, 1, psize, d), kv_map),
             pl.BlockSpec((LUT_SIZE,),
                          lambda bb, hh, qi, ki, pos0s, btab: (0,)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -177,7 +176,7 @@ def paged_prefill_qattention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), jnp.int8),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),
@@ -202,8 +201,8 @@ def _paged_prefill_q4_kernel(bq, psize, pos0_ref, btab_ref, q_ref, k_ref,
     # btab[b, k], so btab_ref[b_i, k_i] names the scale of the loaded tile)
     def load(kr, vr, b_i, k_i):
         pg = btab_ref[b_i, k_i]
-        return (dequant_kv_tile(kr[0, :, 0], ks_ref[pg]),
-                dequant_kv_tile(vr[0, :, 0], vs_ref[pg]))
+        return (dequant_kv_tile(kr[0, 0], ks_ref[pg]),
+                dequant_kv_tile(vr[0, 0], vs_ref[pg]))
 
     _prefill_body(bq, psize, load, pos0_ref, q_ref, k_ref, v_ref, lut_ref,
                   mi_ref, si_ref, inv_ref, osc_ref, o_ref, m_scr, den_scr,
@@ -213,7 +212,7 @@ def _paged_prefill_q4_kernel(bq, psize, pos0_ref, btab_ref, q_ref, k_ref,
 @functools.partial(jax.jit, static_argnames=("bq", "interpret"))
 def paged_prefill_qattention_q4(
     q_i8: jax.Array,          # int8 (B, H, S, D) — chunk queries, ungrouped
-    k_pool: jax.Array,        # uint8 (n_pages, P, Hkv, D//2) — packed pool
+    k_pool: jax.Array,        # uint8 (n_pages, Hkv, P, D//2) — packed pool
     v_pool: jax.Array,
     k_scale: jax.Array,       # fp32 (n_pages,): shared dequant scale per page
     v_scale: jax.Array,
@@ -228,8 +227,7 @@ def paged_prefill_qattention_q4(
     in-kernel under its shared fp32 page scale.  Bit-exact vs
     ``ref.py::paged_prefill_qattention_q4_ref``."""
     b, h, sq, d = q_i8.shape
-    psize = k_pool.shape[1]
-    hkv = k_pool.shape[2]
+    hkv, psize = k_pool.shape[1], k_pool.shape[2]
     dp = k_pool.shape[3]                          # D//2 packed bytes
     assert dp * 2 == d, (dp, d)
     group = h // hkv
@@ -245,8 +243,8 @@ def paged_prefill_qattention_q4(
         in_specs=[
             pl.BlockSpec((1, 1, bq, d),
                          lambda bb, hh, qi, ki, pos0s, btab: (bb, hh, qi, 0)),
-            pl.BlockSpec((1, psize, 1, dp), kv_map),
-            pl.BlockSpec((1, psize, 1, dp), kv_map),
+            pl.BlockSpec((1, 1, psize, dp), kv_map),
+            pl.BlockSpec((1, 1, psize, dp), kv_map),
             pl.BlockSpec((LUT_SIZE,),
                          lambda bb, hh, qi, ki, pos0s, btab: (0,)),
             pl.BlockSpec(memory_space=pltpu.SMEM),    # k page scales
@@ -269,7 +267,7 @@ def paged_prefill_qattention_q4(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), jnp.int8),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

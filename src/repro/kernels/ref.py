@@ -140,7 +140,7 @@ def decode_qattention_ref(
 
 def paged_decode_qattention_ref(
     q_i8: jax.Array,          # int8 (B, Hkv, G, D) — one query token per slot
-    k_pool: jax.Array,        # int8 (n_pages, P, Hkv, D) — global page pool
+    k_pool: jax.Array,        # int8 (n_pages, Hkv, P, D) — global page pool
     v_pool: jax.Array,
     block_tables: jax.Array,  # int32 (B, max_blocks): slot -> pool pages
     lengths: jax.Array,       # int32 (B,): valid rows per slot
@@ -161,7 +161,7 @@ def paged_decode_qattention_ref(
     from repro.core.qsoftmax import LUT_SIZE
 
     b, hkv, g, d = q_i8.shape
-    psize = k_pool.shape[1]
+    psize = k_pool.shape[2]
     nb = block_tables.shape[1]
     neg_init = -(1 << 30)
     m = jnp.full((b, hkv, g, 1), neg_init, jnp.int32)
@@ -171,8 +171,8 @@ def paged_decode_qattention_ref(
     inv = jnp.asarray(inv_s_logit, jnp.float32)
     for k_i in range(nb):
         pg = block_tables[:, k_i]                          # (B,)
-        kb = jnp.take(k_pool, pg, axis=0).transpose(0, 2, 1, 3)  # (B,Hkv,P,D)
-        vb = jnp.take(v_pool, pg, axis=0).transpose(0, 2, 1, 3)
+        kb = jnp.take(k_pool, pg, axis=0)            # (B,Hkv,P,D)
+        vb = jnp.take(v_pool, pg, axis=0)
         s = jax.lax.dot_general(
             q_i8, kb, (((3,), (3,)), ((0, 1), (0, 1))),
             preferred_element_type=jnp.int32)              # (B,Hkv,G,P)
@@ -201,7 +201,7 @@ def paged_decode_qattention_ref(
 
 def paged_prefill_qattention_ref(
     q_i8: jax.Array,          # int8 (B, H, Sq, D) — chunk queries, ungrouped
-    k_pool: jax.Array,        # int8 (n_pages, P, Hkv, D) — global page pool
+    k_pool: jax.Array,        # int8 (n_pages, Hkv, P, D) — global page pool
     v_pool: jax.Array,
     block_tables: jax.Array,  # int32 (B, max_blocks): slot -> pool pages
     pos0: jax.Array,          # int32 (B,): chunk start position per slot
@@ -229,8 +229,7 @@ def paged_prefill_qattention_ref(
     from repro.core.qsoftmax import LUT_SIZE
 
     b, h, sq, d = q_i8.shape
-    psize = k_pool.shape[1]
-    hkv = k_pool.shape[2]
+    hkv, psize = k_pool.shape[1], k_pool.shape[2]
     group = h // hkv
     nb = block_tables.shape[1]
     neg_init = -(1 << 30)
@@ -242,8 +241,8 @@ def paged_prefill_qattention_ref(
     qpos = pos0[:, None] + jnp.arange(sq, dtype=jnp.int32)[None, :]  # (B,Sq)
     for k_i in range(nb):
         pg = block_tables[:, k_i]                          # (B,)
-        kb = jnp.take(k_pool, pg, axis=0).transpose(0, 2, 1, 3)  # (B,Hkv,P,D)
-        vb = jnp.take(v_pool, pg, axis=0).transpose(0, 2, 1, 3)
+        kb = jnp.take(k_pool, pg, axis=0)            # (B,Hkv,P,D)
+        vb = jnp.take(v_pool, pg, axis=0)
         kb = jnp.repeat(kb, group, axis=1)                 # (B,H,P,D)
         vb = jnp.repeat(vb, group, axis=1)
         s = jax.lax.dot_general(

@@ -15,16 +15,24 @@ import numpy as np
 from jax.sharding import Mesh
 
 
+def _auto_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis in Auto mode: the model code places
+    activations through ``with_sharding_constraint`` and leaves gathers to
+    the partitioner, which Explicit axes (``make_mesh``'s default) refuse."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Whatever-fits mesh for CPU tests: (1, n_devices//model, model)."""
     n = len(jax.devices())
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _auto_mesh((n // model, model), ("data", "model"))
 
 
 def make_tp_mesh(model: int = 1):

@@ -13,16 +13,25 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, smoke_config
+from repro.launch.compile_cache import configure_compile_cache
 from repro.models import fold as F
 from repro.models import transformer as T
 from repro.serve.engine import EngineConfig, Request, make_engine
 
 
 def calibrated_folded(cfg, key, calib_tokens):
-    params = T.init_params(cfg, key)
+    """Seeded float params -> one calibration forward -> integer fold.
+
+    Each phase is one jitted program, so XLA fuses the fold's float
+    temporaries instead of materializing an f32 copy of every stacked
+    weight (which would not fit one 16 GB chip at 4B parameters).  The
+    float params are local: they are freed when this returns, before any
+    engine is built, and only the folded integer tree stays on the device."""
+    params = jax.jit(T.init_params, static_argnums=0)(cfg, key)
     amax = T.init_amax(cfg)
-    _, obs, _ = T.forward(cfg, params, amax, calib_tokens)
-    return F.fold_params(cfg, params, obs)
+    _, obs, _ = jax.jit(T.forward, static_argnums=0)(cfg, params, amax,
+                                                      calib_tokens)
+    return jax.jit(F.fold_params, static_argnums=0)(cfg, params, obs)
 
 
 def main():
@@ -36,6 +45,7 @@ def main():
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
 
+    configure_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     key = jax.random.PRNGKey(0)
     calib = jax.random.randint(key, (2, 32), 0, cfg.vocab_size)

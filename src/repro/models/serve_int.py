@@ -155,7 +155,7 @@ def _qkv_rope(x_i8, f, cfg, pos):
 def _flash_bkv(rows: int) -> int:
     """Largest KV block <= 512 that divides ``rows`` (flash_qattention_jax
     tiles the KV axis exactly)."""
-    from repro.kernels.pallas_compat import divisor_tile
+    from repro.kernels.tiling import divisor_tile
     return divisor_tile(512, rows)
 
 
@@ -286,29 +286,41 @@ def _is_kv4(cslot) -> bool:
     return isinstance(cslot, dict) and "ks" in cslot
 
 
+def _seq_major(pages):
+    """(B, nb, Hkv, P, hd) gathered head-major pages -> the slot-major
+    contiguous (B, nb*P, Hkv, hd) view the jnp attention paths read."""
+    b, nb, hkv, psize, hd = pages.shape
+    return pages.transpose(0, 1, 3, 2, 4).reshape(b, nb * psize, hkv, hd)
+
+
+def _paged_view(pool, block_tables):
+    """Gather a slot-major contiguous KV view out of the int8 pool (jnp
+    fallback path only — the Pallas kernels walk the block table)."""
+    return _seq_major(jnp.take(pool, block_tables, axis=0))
+
+
 @kernel_boundary(why="gathered-view int4 dequant on the jnp fallback path; "
-                     "the Pallas kernels do this per tile in VMEM",
-                 static_argnums=(3, 4))
-def _dequant_paged_view(pool_u8, scales, block_tables, nkv_loc, hd):
+                     "the Pallas kernels do this per tile in VMEM")
+def _dequant_paged_view(pool_u8, scales, block_tables):
     """Gather a slot-major contiguous KV view out of the PACKED pool and
     dequantize it (jnp fallback path only — the Pallas kernels dequantize
     per tile in VMEM and never build this).  (B, max_blocks*P, Hkv_loc, hd)
     int8.  Registered kernel boundary: the pool-scale float cast inside is
     the audited exemption on the ref backend."""
     from repro.core import packing
-    b = block_tables.shape[0]
-    pg = jnp.take(pool_u8, block_tables, axis=0)      # (B,nb,P,Hkv,hd/2) u8
+    pg = jnp.take(pool_u8, block_tables, axis=0)      # (B,nb,Hkv,P,hd/2) u8
     sc = jnp.take(scales, block_tables, axis=0)       # (B,nb)
     c4 = packing.unpack_int4_planar(pg, axis=-1)
-    c8 = packing.dequant_int4_codes(c4, sc[:, :, None, None, None])
-    return c8.reshape(b, -1, nkv_loc, hd)
+    return _seq_major(
+        packing.dequant_int4_codes(c4, sc[:, :, None, None, None]))
 
 
 def _attn_decode_paged(x_i8, f, cfg, cache, pos_offset, block_tables,
                        tp_axis=None):
-    """Paged decode step: x (B,1,d); cache {'k','v'}: (n_pages, P, Hkv, hd)
-    int8 global page pool; ``block_tables`` (B, max_blocks) int32 maps each
-    slot's logical KV blocks onto pool pages.
+    """Paged decode step: x (B,1,d); cache {'k','v'}: (n_pages, Hkv, P, hd)
+    int8 global page pool (head-major: one kv head's (P, hd) page tile is
+    the block the Pallas kernels DMA); ``block_tables`` (B, max_blocks)
+    int32 maps each slot's logical KV blocks onto pool pages.
 
     The K/V row for this token is scattered through the slot's block table
     (page = table[b, pos // P], row = pos % P); attention then reads the
@@ -332,8 +344,7 @@ def _attn_decode_paged(x_i8, f, cfg, cache, pos_offset, block_tables,
     assert not cfg.sliding_window, \
         "paged cache serves full-attention archs; SWA keeps the ring buffer"
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    psize = cache["k"].shape[1]
-    nkv_loc = cache["k"].shape[2]                         # Hkv / tp
+    nkv_loc, psize = cache["k"].shape[1:3]                # Hkv / tp, P
     pos_vec = _pos_vector(pos_offset, b)                  # (B,) int32
     qc, kc, vc = _decode_qkv(x_i8, f, cfg, pos_vec)
     aq = f["attn_q"]
@@ -365,14 +376,14 @@ def _attn_decode_paged(x_i8, f, cfg, cache, pos_offset, block_tables,
         vs_pg = jnp.where(row == 0, vs_fresh, cache["vs"][pg])
         kq = packing.quantize_kv_page(kc[:, 0], ks_pg[:, None, None])
         vq = packing.quantize_kv_page(vc[:, 0], vs_pg[:, None, None])
-        k_pool = cache["k"].at[pg, row].set(kq)
-        v_pool = cache["v"].at[pg, row].set(vq)
+        k_pool = cache["k"].at[pg, :, row].set(kq)
+        v_pool = cache["v"].at[pg, :, row].set(vq)
         npool = {"k": k_pool, "v": v_pool,
                  "ks": cache["ks"].at[pg].set(ks_pg),
                  "vs": cache["vs"].at[pg].set(vs_pg)}
     else:
-        k_pool = cache["k"].at[pg, row].set(kc[:, 0])
-        v_pool = cache["v"].at[pg, row].set(vc[:, 0])
+        k_pool = cache["k"].at[pg, :, row].set(kc[:, 0])
+        v_pool = cache["v"].at[pg, :, row].set(vc[:, 0])
         npool = {"k": k_pool, "v": v_pool}
     lengths = pos_vec + 1
     qg = qc.reshape(b, nkv_loc, group, hd)                # (B,kv,g,hd) int8
@@ -395,14 +406,11 @@ def _attn_decode_paged(x_i8, f, cfg, cache, pos_offset, block_tables,
         # makes the result bit-identical to the contiguous layout (int8)
         # resp. to the kernel's fused per-tile dequant (kv4)
         if kv4:
-            k_view = _dequant_paged_view(k_pool, npool["ks"], block_tables,
-                                         nkv_loc, hd)
-            v_view = _dequant_paged_view(v_pool, npool["vs"], block_tables,
-                                         nkv_loc, hd)
+            k_view = _dequant_paged_view(k_pool, npool["ks"], block_tables)
+            v_view = _dequant_paged_view(v_pool, npool["vs"], block_tables)
         else:
-            kv_shape = (b, -1, nkv_loc, hd)
-            k_view = jnp.take(k_pool, block_tables, axis=0).reshape(kv_shape)
-            v_view = jnp.take(v_pool, block_tables, axis=0).reshape(kv_shape)
+            k_view = _paged_view(k_pool, block_tables)
+            v_view = _paged_view(v_pool, block_tables)
         ctx = _gqa_decode_jnp(qg, k_view, v_view, lengths, aq)
     if tp_axis is not None:
         # reassemble full heads (rank order == head order): int8 values
@@ -443,8 +451,7 @@ def _attn_prefill_paged(x_i8, f, cfg, cache, pos, block_tables, pos0,
     b, s, d = x_i8.shape
     wb = cfg.quant.w_bits
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    psize = cache["k"].shape[1]
-    nkv_loc = cache["k"].shape[2]                         # Hkv / tp
+    nkv_loc, psize = cache["k"].shape[1:3]                # Hkv / tp, P
     qc, kc, vc = _qkv_rope(x_i8, f, cfg, pos)
     aq = f["attn_q"]
     kv4 = _is_kv4(cache)
@@ -466,9 +473,8 @@ def _attn_prefill_paged(x_i8, f, cfg, cache, pos, block_tables, pos0,
     # written page's from the whole page), so it always takes the q7
     # paged family — the quality-A/B contract, not the identity one
     if row_exact and not kv4:
-        kv_shape = (b, -1, nkv_loc, hd)
-        k_view = jnp.take(ncache["k"], block_tables, axis=0).reshape(kv_shape)
-        v_view = jnp.take(ncache["v"], block_tables, axis=0).reshape(kv_shape)
+        k_view = _paged_view(ncache["k"], block_tables)
+        v_view = _paged_view(ncache["v"], block_tables)
         rows = k_view.shape[1]
         qpos = pos0 + jnp.arange(s, dtype=jnp.int32)[:, None]
         kpos = jnp.arange(rows, dtype=jnp.int32)[None, :]
@@ -508,13 +514,14 @@ def _paged_prefill_write(cache, kc, vc, block_tables, kc_full=None,
     (``kc_full``/``vc_full``, pre-TP-slice — every rank derives the same
     scale) and nibble-packed along hd; the scale leaves update in the same
     scatter so payload and scale always travel together."""
-    psize = cache["k"].shape[1]
+    psize = cache["k"].shape[2]
     b, s = kc.shape[0], kc.shape[1]
     nb = s // psize
     assert nb * psize == s and block_tables.shape[1] == nb, \
         (s, psize, block_tables.shape)
-    kr = kc.reshape(b, nb, psize, *kc.shape[2:])
-    vr = vc.reshape(b, nb, psize, *vc.shape[2:])
+    # (B, S, Hkv, hd) rows -> (B, nb, Hkv, P, hd) head-major pages
+    kr = kc.reshape(b, nb, psize, *kc.shape[2:]).swapaxes(2, 3)
+    vr = vc.reshape(b, nb, psize, *vc.shape[2:]).swapaxes(2, 3)
     if _is_kv4(cache):
         from repro.core import packing
         kfr = (kc if kc_full is None else kc_full).reshape(
@@ -565,8 +572,7 @@ def _attn_verify_paged(x_i8, f, cfg, cache, pos_vec, block_tables, n_rows,
     projection), so sharded verify stays bit-identical to unsharded."""
     b, s, d = x_i8.shape
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    psize = cache["k"].shape[1]
-    nkv_loc = cache["k"].shape[2]                         # Hkv / tp
+    nkv_loc, psize = cache["k"].shape[1:3]                # Hkv / tp, P
     assert not _is_kv4(cache), \
         "speculative verify serves the int8 pool (spec x kv4: ROADMAP)"
     assert cfg.mrope_sections is None, \
@@ -589,15 +595,14 @@ def _attn_verify_paged(x_i8, f, cfg, cache, pos_vec, block_tables, n_rows,
     pg = jnp.take_along_axis(block_tables, positions // psize, axis=1)
     pg = jnp.where(valid, pg, 0)
     row = jnp.where(valid, positions % psize, 0)
-    ncache = {"k": cache["k"].at[pg, row].set(kc),
-              "v": cache["v"].at[pg, row].set(vc)}
+    ncache = {"k": cache["k"].at[pg, :, row].set(kc),
+              "v": cache["v"].at[pg, :, row].set(vc)}
     if row_exact:
         # gathered chain view + per-slot causal frontier: row i of slot b
         # attends rows [0, pos_vec[b] + i] — bit-identical to the decode
         # step at that position (see _attn_rows_q8)
-        kv_shape = (b, -1, nkv_loc, hd)
-        k_view = jnp.take(ncache["k"], block_tables, axis=0).reshape(kv_shape)
-        v_view = jnp.take(ncache["v"], block_tables, axis=0).reshape(kv_shape)
+        k_view = _paged_view(ncache["k"], block_tables)
+        v_view = _paged_view(ncache["v"], block_tables)
         rows = k_view.shape[1]
         kpos = jnp.arange(rows, dtype=jnp.int32)[None, None, :]
         ctx = _attn_rows_q8(qc, k_view, v_view, aq, cfg,
@@ -821,14 +826,16 @@ def paged_page_nbytes(cfg: ModelConfig, page_size: int,
 
 def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
                      kv_bits: int = 8) -> Dict:
-    """Global paged KV pool, stacked (n_reps, n_pages, P, Hkv, hd) per attn
-    slot.  Pages are position-agnostic: a slot's (max_blocks,) block-table
-    row, not the pool layout, decides which rows belong to which request.
+    """Global paged KV pool, stacked (n_reps, n_pages, Hkv, P, hd) per attn
+    slot (head-major pages: each kv head's (P, hd) tile is contiguous and
+    is the block the Pallas kernels stream).  Pages are position-agnostic:
+    a slot's (max_blocks,) block-table row, not the pool layout, decides
+    which rows belong to which request.
     Only all-attention archs page (SSM/xLSTM state is O(1) per slot and
     SWA already ring-buffers to the window).
 
     ``kv_bits=4`` switches each slot to the PACKED layout: payload leaves
-    become (n_reps, n_pages, P, Hkv, hd//2) uint8 (nibble-planar along hd —
+    become (n_reps, n_pages, Hkv, P, hd//2) uint8 (nibble-planar along hd —
     half the pool bytes) plus per-page fp32 shared-scale leaves 'ks'/'vs'
     of shape (n_reps, n_pages).  The trash-page scale initializes to the
     all-zero page's well-defined scale (1/7) so dead reads stay exact
@@ -840,7 +847,7 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
     if kv_bits == 4:
         from repro.core import packing
         assert cfg.hd % 2 == 0, cfg.hd
-        shape = (cfg.n_reps, n_pages, page_size, cfg.n_kv_heads, cfg.hd // 2)
+        shape = (cfg.n_reps, n_pages, cfg.n_kv_heads, page_size, cfg.hd // 2)
         sshape = (cfg.n_reps, n_pages)
         # NB: one jnp.full per leaf — sharing a single scale array across
         # leaves would alias buffers and break donate_argnums on the pool
@@ -850,7 +857,7 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
                              "v": jnp.zeros(shape, jnp.uint8),
                              "ks": s0(), "vs": s0()}
                 for i in range(len(kinds))}
-    shape = (cfg.n_reps, n_pages, page_size, cfg.n_kv_heads, cfg.hd)
+    shape = (cfg.n_reps, n_pages, cfg.n_kv_heads, page_size, cfg.hd)
     return {f"slot{i}": {"k": jnp.zeros(shape, jnp.int8),
                          "v": jnp.zeros(shape, jnp.int8)}
             for i in range(len(kinds))}

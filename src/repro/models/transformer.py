@@ -189,9 +189,14 @@ def init_params(cfg: ModelConfig, key) -> Dict:
     keys = jax.random.split(key, len(kinds) * cfg.n_reps + 4)
     blocks = {}
     for i, (mixer, ffn) in enumerate(kinds):
-        reps = [init_slot_params(cfg, mixer, ffn, keys[i * cfg.n_reps + r])
-                for r in range(cfg.n_reps)]
-        blocks[f"slot{i}"] = jax.tree.map(lambda *xs: jnp.stack(xs), *reps)
+        # one batched init over the rep keys: the same values as stacking
+        # per-rep inits (random sampling is vmap-consistent), without a
+        # second copy of every stacked leaf or an n_reps-times unrolled
+        # program when jitted
+        rep_keys = keys[i * cfg.n_reps:(i + 1) * cfg.n_reps]
+        blocks[f"slot{i}"] = jax.vmap(
+            lambda k, mixer=mixer, ffn=ffn: init_slot_params(cfg, mixer, ffn,
+                                                             k))(rep_keys)
     p = {
         "embed": {"tokens": (jax.random.normal(keys[-1], (cfg.vocab_size, cfg.d_model))
                              * 0.02).astype(cfg.dtype)},

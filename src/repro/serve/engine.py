@@ -101,6 +101,7 @@ prefill replayed token-by-token for the whole batch, admission only between
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import enum
 import math
@@ -224,6 +225,8 @@ class EngineConfig:
     mesh: object = None
     spec_k: int = 0                       # max draft tokens/slot/tick (0=off)
     draft: object = "prompt_lookup"       # DraftSource instance or name
+    device: object = None                 # jax Device for params + pool
+                                          # (None: JAX's default device)
 
     @classmethod
     def from_kwargs(cls, **kw) -> "EngineConfig":
@@ -288,6 +291,10 @@ class EngineConfig:
                 self.cache_layout == "contiguous":
             bad("tensor parallelism shards the paged KV pool; "
                 "cache_layout='contiguous' has no TP path")
+        if self.device is not None and (self.tp != 1
+                                        or self.mesh is not None):
+            bad("device places a single-rank engine; a tensor-parallel "
+                "engine's devices come from its mesh")
         if self.spec_k < 0:
             bad(f"spec_k must be >= 0 (got {self.spec_k})")
         if self.spec_k > 0 and self.cache_layout == "contiguous":
@@ -308,7 +315,7 @@ _DEFAULT_CONFIG = EngineConfig()
 _CONTINUOUS_ONLY_FIELDS = ("prefill_bucket", "cache_layout", "page_size",
                            "n_pages", "max_batched_tokens",
                            "max_prefill_chunk", "reserve_policy", "kv_bits",
-                           "tp", "mesh", "spec_k", "draft")
+                           "tp", "mesh", "spec_k", "draft", "device")
 
 
 def _config_only(config: Optional[EngineConfig], kw: dict,
@@ -363,7 +370,11 @@ class Engine:
                 f"continuous engine serves token-LM archs; arch "
                 f"{cfg.name!r} needs LockstepEngine (use make_engine)")
         self.cfg = cfg
-        self.folded = folded
+        # one-chip replicas each live on their own device: params here, the
+        # pool in _init_state; jitted steps then run where their inputs are
+        self.device = config.device
+        self.folded = (folded if self.device is None
+                       else jax.device_put(folded, self.device))
         self.config = config
         batch_slots, max_len = config.batch_slots, config.max_len
         cache_layout, page_size = config.cache_layout, config.page_size
@@ -467,6 +478,10 @@ class Engine:
                 raise EngineConfigError(
                     f"TP={self.tp} must divide n_kv_heads={cfg.n_kv_heads} "
                     "(each rank owns a whole slice of KV heads)")
+            # params replicate on every rank once, not per forward call
+            from jax.sharding import NamedSharding, PartitionSpec
+            self.folded = jax.device_put(
+                self.folded, NamedSharding(mesh, PartitionSpec()))
         else:
             self.tp = 1
         # cross-replica shared prefix tier (attach_prefix_tier): survives
@@ -503,19 +518,21 @@ class Engine:
                 # per-leaf specs: kv4 pools carry 2-D (n_reps, n_pages)
                 # scale leaves next to the 5-D packed payloads, so one
                 # broadcast pspec would rank-mismatch — match each leaf
+                # (the forward promises replicated outputs itself through
+                # its all_gathers, so the replication check stays off)
                 pool, rep = Pt.kv_pool_specs(self.cache, self.mesh), P()
-                decode_step = Pt.shard_map_compat(
-                    decode_step, self.mesh,
+                decode_step = jax.shard_map(
+                    decode_step, mesh=self.mesh,
                     in_specs=(rep, pool, rep, rep, rep),
-                    out_specs=(rep, pool))
-                prefill = Pt.shard_map_compat(
-                    prefill, self.mesh,
+                    out_specs=(rep, pool), check_vma=False)
+                prefill = jax.shard_map(
+                    prefill, mesh=self.mesh,
                     in_specs=(rep, pool, rep, rep, rep),
-                    out_specs=(rep, pool))
-                verify = Pt.shard_map_compat(
-                    verify, self.mesh,
+                    out_specs=(rep, pool), check_vma=False)
+                verify = jax.shard_map(
+                    verify, mesh=self.mesh,
                     in_specs=(rep, pool, rep, rep, rep, rep),
-                    out_specs=(rep, pool))
+                    out_specs=(rep, pool), check_vma=False)
             self._decode = jax.jit(decode_step, donate_argnums=(1,))
             # the chunk forward: writes straight through the block table
             # into the (donated) pool at page-aligned ``pos0`` and attends
@@ -576,8 +593,9 @@ class Engine:
                                    max_batched_tokens=self.max_batched_tokens,
                                    max_prefill_chunk=self.max_prefill_chunk,
                                    reserve=self.reserve_policy)
-            self.cache = S.init_paged_cache(self.cfg, self.n_pages,
-                                            self.page_size, self.kv_bits)
+            with self._on_device():
+                self.cache = S.init_paged_cache(self.cfg, self.n_pages,
+                                                self.page_size, self.kv_bits)
             if self.mesh is not None:
                 # lay the pool out sharded before the first donated step so
                 # every forward reuses the same per-rank Hkv-slice buffers
@@ -589,7 +607,12 @@ class Engine:
         else:
             self.alloc = None
             self.sched = Scheduler(self.batch)
-            self.cache = S.init_cache(self.cfg, self.batch, self.max_len)
+            with self._on_device():
+                self.cache = S.init_cache(self.cfg, self.batch, self.max_len)
+
+    def _on_device(self):
+        return (contextlib.nullcontext() if self.device is None
+                else jax.default_device(self.device))
 
     def reset(self, seed: int = 0):
         """Clear all serving state; keeps the compiled graphs."""

@@ -223,13 +223,13 @@ def cache_sharding(mesh: Mesh, tree):
 # --- TP-sharded paged KV pool ---------------------------------------------------
 
 def kv_pool_pspec() -> P:
-    """PartitionSpec of one paged-pool K/V leaf (n_reps, n_pages, P, Hkv, hd):
+    """PartitionSpec of one paged-pool K/V leaf (n_reps, n_pages, Hkv, P, hd):
     KV heads shard over the model axis, everything else — crucially the PAGE
     axis — stays unsharded.  Page ids are therefore global: every rank holds
     its heads' slice of EVERY page, so one host-side block table / allocator
     decision addresses all ranks identically and spill/restore never moves
     data across ranks."""
-    return P(None, None, None, "model", None)
+    return P(None, None, "model", None, None)
 
 
 def paged_pool_shardings(mesh: Mesh, tree):
@@ -257,21 +257,6 @@ def kv_pool_specs(tree, mesh: Mesh):
             return _fit_spec(kv_pool_pspec(), v.shape, mesh)
         return P(*([None] * v.ndim))
     return jax.tree.map(spec, tree)
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """``shard_map`` with per-rank (unchecked) replication semantics across
-    the jax rename: 0.4.x has ``jax.experimental.shard_map`` with
-    ``check_rep``; newer jax promotes it to ``jax.shard_map`` and renames
-    the flag ``check_vma``.  Callers use collectives (all_gather) and
-    promise replicated outputs themselves, so the check is always off."""
-    try:
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-    except (ImportError, TypeError):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
 
 
 # --- activation-constraint context ---------------------------------------------

@@ -150,21 +150,54 @@ def test_hw_constants_single_source_no_drift():
     import importlib.util
     from pathlib import Path
 
+    from repro.analysis import pallas_lint
     from repro.kernels import hw_constants as HW
 
-    assert autotune.VMEM_BUDGET is HW.VMEM_BUDGET
-    assert autotune.VMEM_FILL is HW.VMEM_FILL
-    assert autotune.HBM_BW is HW.HBM_BW
-    assert autotune.PEAK_INT8_FLOPS is HW.PEAK_INT8_FLOPS
-    assert autotune.STEP_OVERHEAD_S is HW.STEP_OVERHEAD_S
+    assert autotune.HW is HW
+    # off the TPU the tuner prices the kernels' target chip, named explicitly
+    assert autotune.target_chip() is HW.V5E
+    assert pallas_lint.VMEM_BUDGET is HW.V5E.vmem_budget
+    assert pallas_lint.VMEM_FILL is HW.VMEM_FILL
 
     roofline_py = (Path(__file__).resolve().parents[1] / "benchmarks"
                    / "roofline.py")
     spec = importlib.util.spec_from_file_location("roofline", roofline_py)
     roofline = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(roofline)
-    assert roofline.PEAK_FLOPS is HW.PEAK_FLOPS
-    assert roofline.HBM_BW is HW.HBM_BW
-    assert roofline.ICI_BW is HW.ICI_BW
-    assert roofline.ICI_LINKS is HW.ICI_LINKS
-    assert HW.PEAK_FLOPS is HW.PEAK_INT8_FLOPS
+    assert roofline.CHIP is HW.V5E
+
+
+def test_hw_constants_keyed_by_device_kind():
+    """The table is keyed by JAX's device_kind with the published v5e
+    peaks; a kind it lacks raises instead of borrowing another chip's."""
+    from repro.kernels import hw_constants as HW
+
+    v5e = HW.chip("TPU v5 lite")
+    assert v5e is HW.V5E
+    assert v5e.peak_int8_ops == 393e12 and v5e.peak_bf16_flops == 197e12
+    assert v5e.hbm_bw == 819e9
+    assert v5e.ici_links * v5e.ici_bw * 8 == 1600e9     # 1,600 Gbit/s
+    with pytest.raises(ValueError, match="TPU v9"):
+        HW.chip("TPU v9")
+
+
+def test_ops_prefill_autotune_reads_head_major_pool(monkeypatch):
+    """The prefill q-block is priced with the pool's page size and kv-head
+    count read off the head-major (n_pages, Hkv, P, D) pool."""
+    import jax.numpy as jnp
+
+    from repro.kernels import ops
+
+    seen = {}
+
+    def fake(sq, **kw):
+        seen.update(kw, sq=sq)
+        return 8
+
+    monkeypatch.setattr(autotune, "prefill_bq", fake)
+    q = jnp.zeros((1, 4, 16, 32), jnp.int8)          # (B, H, S, D)
+    pool = jnp.zeros((5, 2, 8, 32), jnp.int8)        # (n_pages, Hkv, P, D)
+    btab = jnp.zeros((1, 3), jnp.int32)
+    assert ops._autotuned_bq(q, pool, btab, kv_bits=8) == 8
+    assert seen == dict(sq=16, batch_slots=1, page_size=8, hkv=2, hd=32,
+                        kv_bits=8, n_blocks=3, n_heads=4)

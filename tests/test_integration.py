@@ -183,3 +183,41 @@ def test_vlm_loss_masks_image_positions():
     step = jax.jit(St.make_train_step(cfg, opt))
     state, m = step(state, batch)
     assert np.isfinite(float(m["loss"]))
+
+
+def test_chip_smoke_refuses_without_tpu(capsys, monkeypatch):
+    """chip_smoke.py checks the chip only: without a TPU it exits non-zero
+    and prints no result line (no silent CPU fallback)."""
+    import importlib.util
+    import sys
+    from pathlib import Path
+
+    path = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    spec = importlib.util.spec_from_file_location("chip_smoke", path)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+    monkeypatch.setattr(sys, "argv", ["chip_smoke.py"])
+    assert jax.devices()[0].platform != "tpu"
+    assert smoke.main() != 0
+    assert capsys.readouterr().out == ""
+
+
+def test_compile_cache_placement(monkeypatch):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says, with nothing set in code; otherwise to <checkout>/.jax_cache."""
+    from pathlib import Path
+
+    from repro.launch.compile_cache import configure_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        jax.config.update("jax_compilation_cache_dir", prev)
+        assert configure_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+        assert configure_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -167,6 +167,23 @@ def test_ops_dispatch_ref_vs_interpret():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+def test_ops_w8_linear_runs_bitsplit_kernel():
+    """w_bits=8 linears go through the bit-split kernel off the ref backend
+    (no silent jnp fallback), bit-exact against its oracle."""
+    from repro.core.qlinear import FoldedLinear
+    x = RNG.integers(-127, 128, (5, 128)).astype(np.int8)
+    w = RNG.integers(-128, 128, (128, 64)).astype(np.int8)
+    M, sh = fxp.quantize_multiplier(0.0005)
+    f = FoldedLinear(jnp.asarray(w), jnp.arange(64, dtype=jnp.int32),
+                     jnp.int32(M), jnp.int32(sh), 8)
+    a = ops.linear_w4a8(jnp.asarray(x), f, impl="ref")
+    jaxpr = jax.make_jaxpr(
+        lambda xx: ops.linear_w4a8(xx, f, impl="interpret"))(jnp.asarray(x))
+    assert "pallas_call" in str(jaxpr)
+    b = ops.linear_w4a8(jnp.asarray(x), f, impl="interpret")
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.parametrize("bkv", [128, 32, 64, 48])  # 48: falls back to a
 def test_decode_attention_batched_per_slot_lengths(bkv):  # divisor of smax
     """Continuous-batching decode kernel: every slot masked to its own cache
@@ -219,13 +236,20 @@ def test_decode_attention_ops_dispatch():
     np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
 
 
+def _seq_view(pool, btab):
+    """Gathered contiguous (B, nb*P, Hkv, D) view of head-major pool pages."""
+    pages = np.asarray(pool)[np.asarray(btab)]          # (B, nb, Hkv, P, D)
+    b, nb, hkv, psize, d = pages.shape
+    return pages.transpose(0, 1, 3, 2, 4).reshape(b, nb * psize, hkv, d)
+
+
 def _paged_inputs(b, hkv, g, d, psize, n_pages, nb, lengths, seed=31):
     """Random pool + per-slot block tables (distinct pages per slot; unused
     table entries alias the trash page 0, like the serving engine's)."""
     rng = np.random.default_rng(seed)
     q = rng.integers(-64, 65, (b, hkv, g, d)).astype(np.int8)
-    kp = rng.integers(-64, 65, (n_pages, psize, hkv, d)).astype(np.int8)
-    vp = rng.integers(-64, 65, (n_pages, psize, hkv, d)).astype(np.int8)
+    kp = rng.integers(-64, 65, (n_pages, hkv, psize, d)).astype(np.int8)
+    vp = rng.integers(-64, 65, (n_pages, hkv, psize, d)).astype(np.int8)
     perm = iter(rng.permutation(np.arange(1, n_pages)))
     btab = np.zeros((b, nb), np.int32)
     for bb, ln in enumerate(lengths):
@@ -262,10 +286,7 @@ def test_paged_decode_attention_bit_exact_vs_oracle(psize, lengths):
     np.testing.assert_array_equal(got, want)
     # the gathered contiguous view through the row oracle stays within the
     # documented 1-LSB flash tolerance (exact when one page covers a slot)
-    kv = np.asarray(jnp.take(jnp.asarray(kp), jnp.asarray(btab), axis=0)
-                    ).reshape(b, nb * psize, hkv, d)
-    vv = np.asarray(jnp.take(jnp.asarray(vp), jnp.asarray(btab), axis=0)
-                    ).reshape(b, nb * psize, hkv, d)
+    kv, vv = _seq_view(kp, btab), _seq_view(vp, btab)
     row = np.asarray(R.decode_qattention_ref(
         jnp.asarray(q), jnp.asarray(kv.transpose(0, 2, 1, 3)),
         jnp.asarray(vv.transpose(0, 2, 1, 3)),
@@ -305,8 +326,8 @@ def test_paged_matches_contiguous_decode_kernel():
     lengths = np.asarray([29, 64], np.int32)
     nb = smax // psize
     # pool = per-slot stripes split into pages; table = identity chains
-    kp = k.reshape(b * nb, psize, hkv, d)
-    vp = v.reshape(b * nb, psize, hkv, d)
+    kp = k.reshape(b * nb, psize, hkv, d).transpose(0, 2, 1, 3)
+    vp = v.reshape(b * nb, psize, hkv, d).transpose(0, 2, 1, 3)
     btab = np.arange(b * nb, dtype=np.int32).reshape(b, nb)
     s_logit = 1.0 / (0.05 * np.sqrt(d))
     M, sh = fxp.quantize_multiplier(1.0 / (s_logit * qs.LUT_DELTA))
@@ -360,8 +381,8 @@ def _paged_prefill_inputs(b, h, hkv, d, psize, nb, sq, pos0, seed=37):
     rng = np.random.default_rng(seed)
     q = rng.integers(-64, 65, (b, h, sq, d)).astype(np.int8)
     n_pages = b * nb + 1
-    kp = rng.integers(-64, 65, (n_pages, psize, hkv, d)).astype(np.int8)
-    vp = rng.integers(-64, 65, (n_pages, psize, hkv, d)).astype(np.int8)
+    kp = rng.integers(-64, 65, (n_pages, hkv, psize, d)).astype(np.int8)
+    vp = rng.integers(-64, 65, (n_pages, hkv, psize, d)).astype(np.int8)
     perm = iter(rng.permutation(np.arange(1, n_pages)))
     btab = np.zeros((b, nb), np.int32)
     for bb in range(b):
@@ -401,10 +422,7 @@ def test_paged_prefill_kernel_bit_exact_vs_oracle(psize, sq, pos0, bq):
     np.testing.assert_array_equal(got, want)
     # sanity vs the row oracle on the gathered contiguous view: within the
     # documented flash tolerance (fp32 cross-block carry)
-    kv = np.asarray(jnp.take(jnp.asarray(kp), jnp.asarray(btab), axis=0)
-                    ).reshape(b, nb * psize, hkv, d)
-    vv = np.asarray(jnp.take(jnp.asarray(vp), jnp.asarray(btab), axis=0)
-                    ).reshape(b, nb * psize, hkv, d)
+    kv, vv = _seq_view(kp, btab), _seq_view(vp, btab)
     for bb in range(b):
         row = np.asarray(R.qattention_ref(
             jnp.asarray(q[bb]),

@@ -96,7 +96,7 @@ def test_small_codes_round_trip_exactly():
 # --- q4 kernels vs oracles -----------------------------------------------------
 
 def _pack_pool(pool_i8):
-    """(n_pages, P, Hkv, D) int8 -> packed uint8 pool + (n_pages,) scales,
+    """(n_pages, Hkv, P, D) int8 -> packed uint8 pool + (n_pages,) scales,
     the exact per-page shared-scale quantization the write path performs."""
     pool = jnp.asarray(pool_i8)
     scales = jax.vmap(packing.kv_page_scale)(pool)
@@ -108,8 +108,8 @@ def _pack_pool(pool_i8):
 def _paged_inputs(b, hkv, g, d, psize, n_pages, nb, lengths, seed=31):
     rng = np.random.default_rng(seed)
     q = rng.integers(-64, 65, (b, hkv, g, d)).astype(np.int8)
-    kp = rng.integers(-64, 65, (n_pages, psize, hkv, d)).astype(np.int8)
-    vp = rng.integers(-64, 65, (n_pages, psize, hkv, d)).astype(np.int8)
+    kp = rng.integers(-64, 65, (n_pages, hkv, psize, d)).astype(np.int8)
+    vp = rng.integers(-64, 65, (n_pages, hkv, psize, d)).astype(np.int8)
     perm = iter(rng.permutation(np.arange(1, n_pages)))
     btab = np.zeros((b, nb), np.int32)
     for bb, ln in enumerate(lengths):
@@ -173,8 +173,8 @@ def test_paged_prefill_q4_bit_exact_vs_oracle(psize, sq, pos0, bq):
     rng = np.random.default_rng(37)
     q = rng.integers(-64, 65, (b, h, sq, d)).astype(np.int8)
     n_pages = b * nb + 1
-    kp = rng.integers(-64, 65, (n_pages, psize, hkv, d)).astype(np.int8)
-    vp = rng.integers(-64, 65, (n_pages, psize, hkv, d)).astype(np.int8)
+    kp = rng.integers(-64, 65, (n_pages, hkv, psize, d)).astype(np.int8)
+    vp = rng.integers(-64, 65, (n_pages, hkv, psize, d)).astype(np.int8)
     perm = iter(rng.permutation(np.arange(1, n_pages)))
     btab = np.zeros((b, nb), np.int32)
     for bb in range(b):

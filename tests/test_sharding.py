@@ -78,10 +78,10 @@ def test_make_param_shardings_on_struct_tree():
 
 def test_kv_pool_pspec_shards_only_heads():
     sp = Pt.kv_pool_pspec()
-    # (n_reps, n_pages, P, Hkv, hd): pages MUST stay unsharded — global
+    # (n_reps, n_pages, Hkv, P, hd): pages MUST stay unsharded — global
     # page ids are what keep the host allocator a single authority
-    assert sp == P(None, None, None, "model", None)
-    assert sp[1] is None and sp[3] == "model"
+    assert sp == P(None, None, "model", None, None)
+    assert sp[1] is None and sp[2] == "model"
 
 
 def test_paged_pool_shardings_tree():
@@ -90,7 +90,7 @@ def test_paged_pool_shardings_tree():
                       "v": jax.ShapeDtypeStruct((2, 9, 4, 4, 32), np.int8)}}
     sh = Pt.paged_pool_shardings(mesh, pool)
     for leaf in (sh["slot0"]["k"], sh["slot0"]["v"]):
-        assert leaf.spec == P(None, None, None, "model", None)
+        assert leaf.spec == P(None, None, "model", None, None)
         assert leaf.mesh.shape["model"] == 1
 
 
@@ -99,7 +99,7 @@ def test_paged_pool_shardings_drops_indivisible_heads():
     # replicated rather than erroring (the engine asserts divisibility
     # before ever building such a pool)
     mesh = _fake_mesh(model=4)
-    sp = Pt._fit_spec(Pt.kv_pool_pspec(), (2, 9, 4, 3, 32), mesh)
+    sp = Pt._fit_spec(Pt.kv_pool_pspec(), (2, 9, 3, 4, 32), mesh)
     assert sp == P(None, None, None, None, None)
 
 
@@ -125,10 +125,10 @@ def test_make_tp_mesh_multi_device():
 
 
 def test_shard_map_compat_runs_degenerate():
-    """The compat wrapper must produce a working shard_map on whatever jax
-    version is installed (the CI matrix pins the floor and latest)."""
+    """The TP engine wraps its forwards in ``jax.shard_map`` with the
+    replication check off; that call form must run on a 1-device mesh."""
     mesh = M.make_tp_mesh(1)
-    f = Pt.shard_map_compat(lambda x: x * 2, mesh, in_specs=(P(),),
-                            out_specs=P())
+    f = jax.shard_map(lambda x: x * 2, mesh=mesh, in_specs=(P(),),
+                      out_specs=P(), check_vma=False)
     y = jax.jit(f)(np.arange(4, dtype=np.int32))
     assert np.array_equal(np.asarray(y), np.arange(4) * 2)

@@ -102,12 +102,12 @@ def test_tp4_pool_is_actually_sharded(folded_cfg):
     eng = Engine(cfg, folded, EngineConfig(batch_slots=2, max_len=64,
                                            cache_layout="paged", page_size=4,
                                            tp=4))
-    leaf = eng.cache["slot0"]["k"]       # (n_reps, n_pages, P, Hkv, hd)
+    leaf = eng.cache["slot0"]["k"]       # (n_reps, n_pages, Hkv, P, hd)
     shards = leaf.addressable_shards
     assert len(shards) == 4
     for s in shards:
-        assert s.data.shape == (cfg.n_reps, eng.n_pages, eng.page_size,
-                                cfg.n_kv_heads // 4, cfg.hd)
+        assert s.data.shape == (cfg.n_reps, eng.n_pages, cfg.n_kv_heads // 4,
+                                eng.page_size, cfg.hd)
 
 
 @multi
@@ -229,3 +229,29 @@ def test_tp1_degenerate_shard_map_identity(folded_cfg):
                      page_size=4, max_batched_tokens=16, max_prefill_chunk=8)
     assert tp.mesh is not None and tp.tp == 1
     assert ref.mesh is None              # the A/B really was sharded-vs-not
+
+
+def test_engine_device_places_params_and_pool(folded_cfg):
+    """A one-chip replica pinned to a device keeps its params and pool
+    there across serving, and serves the same tokens as an unpinned
+    engine (the last device: device 3 under the 4-device flag)."""
+    cfg, folded = folded_cfg
+    dev = jax.devices()[-1]
+    kw = dict(batch_slots=2, max_len=64, cache_layout="paged", page_size=4)
+    pinned = Engine(cfg, folded, EngineConfig(**kw, device=dev))
+    plain = Engine(cfg, folded, EngineConfig(**kw))
+    mk = lambda: _requests(cfg, [5, 9], [6, 4], prefix_len=4)
+    got = [r.out.tolist() for r in pinned.generate(mk())]
+    want = [r.out.tolist() for r in plain.generate(mk())]
+    assert got == want
+    held = {d for leaf in jax.tree.leaves((pinned.folded, pinned.cache))
+            for d in leaf.devices()}
+    assert held == {dev}
+
+
+def test_engine_device_rejects_tp(folded_cfg):
+    cfg, folded = folded_cfg
+    with pytest.raises(EngineConfigError, match="device"):
+        Engine(cfg, folded, EngineConfig(batch_slots=2, max_len=64,
+                                         mesh=make_tp_mesh(1),
+                                         device=jax.devices()[0]))
