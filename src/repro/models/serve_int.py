@@ -37,7 +37,8 @@ def _ln(x_i8, f, cfg):
     p = QLNParams(gamma_i=f["gamma_i"], beta_aligned=f["beta_al"],
                   M_out=f["M"], shift_out=f["sh"],
                   subtract_mean=(cfg.norm_type == "layernorm"))
-    return ops.layernorm_q(x_i8, p)
+    with jax.named_scope("norm"):
+        return ops.layernorm_q(x_i8, p)
 
 
 def _lin(x_i8, f, w_bits):
@@ -70,9 +71,11 @@ def _resid_add(x_i8, f_rescale, delta_i8):
 
 
 def _lut8(x_i8, lut_i8):
-    """int8 -> int8 elementwise via 256-entry LUT (one-hot select)."""
-    idx = x_i8.astype(jnp.int32) + 128
-    return jnp.take(lut_i8, idx).astype(jnp.int8)
+    """int8 -> int8 elementwise via 256-entry LUT (one-hot select): the
+    FFN activation, named ``act_lut`` on the device trace."""
+    with jax.named_scope("act_lut"):
+        idx = x_i8.astype(jnp.int32) + 128
+        return jnp.take(lut_i8, idx).astype(jnp.int8)
 
 
 def _rope_island(h_i8, inv_s_in, s_out, pos, cfg, qn=None):
@@ -142,14 +145,17 @@ def _qkv_rope(x_i8, f, cfg, pos):
     b, s, _ = x_i8.shape
     wb = cfg.quant.w_bits
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
-    h = _ln(x_i8, f["ln1"], cfg)
-    qc = _lin(h, f["wq"], wb).reshape(b, s, nh, hd)
-    kc = _lin(h, f["wk"], wb).reshape(b, s, nkv, hd)
-    vc = _lin(h, f["wv"], wb).reshape(b, s, nkv, hd)
-    aq = f["attn_q"]
-    qc = _rope_island(qc, aq["inv_s_qp"], aq["s_q"], pos, cfg, aq.get("qn"))
-    kc = _rope_island(kc, aq["inv_s_kp"], aq["s_k"], pos, cfg, aq.get("kn"))
-    return qc, kc, vc
+    with jax.named_scope("qkv"):
+        h = _ln(x_i8, f["ln1"], cfg)
+        qc = _lin(h, f["wq"], wb).reshape(b, s, nh, hd)
+        kc = _lin(h, f["wk"], wb).reshape(b, s, nkv, hd)
+        vc = _lin(h, f["wv"], wb).reshape(b, s, nkv, hd)
+        aq = f["attn_q"]
+        qc = _rope_island(qc, aq["inv_s_qp"], aq["s_q"], pos, cfg,
+                          aq.get("qn"))
+        kc = _rope_island(kc, aq["inv_s_kp"], aq["s_k"], pos, cfg,
+                          aq.get("kn"))
+        return qc, kc, vc
 
 
 def _flash_bkv(rows: int) -> int:
@@ -165,25 +171,27 @@ def _attn_prefill(x_i8, f, cfg, pos, row_exact: bool = False):
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     qc, kc, vc = _qkv_rope(x_i8, f, cfg, pos)
     aq = f["attn_q"]
-    if cfg.causal and row_exact:
-        # decode-identical rows (see _attn_rows_q8) with a causal/SWA mask
-        qpos = jnp.arange(s, dtype=jnp.int32)[:, None]
-        kpos = jnp.arange(s, dtype=jnp.int32)[None, :]
-        live = kpos <= qpos
-        if cfg.sliding_window:
-            live &= kpos > qpos - cfg.sliding_window
-        ctx = _attn_rows_q8(qc, kc, vc, aq, cfg, live)
-    elif cfg.causal:
-        # blocked integer flash over KV (fp32 carry), per-batch vmap
-        fn = lambda qq, kk, vv: flash_qattention_jax(
-            qq, kk, vv, aq["M_idx"], aq["sh_idx"], _lut_q7(),
-            aq["inv_s_logit"], aq["out_scale"], window=cfg.sliding_window,
-            bkv=_flash_bkv(s))
-        ctx = jax.vmap(fn)(qc.transpose(0, 2, 1, 3), kc.transpose(0, 2, 1, 3),
-                           vc.transpose(0, 2, 1, 3))      # (B,H,S,D) int8
-    else:
-        # bidirectional (BERT): paper-style row LUT softmax, materialized
-        ctx = _attn_rows_q8(qc, kc, vc, aq, cfg, None)
+    with jax.named_scope("core"):
+        if cfg.causal and row_exact:
+            # decode-identical rows (see _attn_rows_q8), causal/SWA mask
+            qpos = jnp.arange(s, dtype=jnp.int32)[:, None]
+            kpos = jnp.arange(s, dtype=jnp.int32)[None, :]
+            live = kpos <= qpos
+            if cfg.sliding_window:
+                live &= kpos > qpos - cfg.sliding_window
+            ctx = _attn_rows_q8(qc, kc, vc, aq, cfg, live)
+        elif cfg.causal:
+            # blocked integer flash over KV (fp32 carry), per-batch vmap
+            fn = lambda qq, kk, vv: flash_qattention_jax(
+                qq, kk, vv, aq["M_idx"], aq["sh_idx"], _lut_q7(),
+                aq["inv_s_logit"], aq["out_scale"],
+                window=cfg.sliding_window, bkv=_flash_bkv(s))
+            ctx = jax.vmap(fn)(qc.transpose(0, 2, 1, 3),
+                               kc.transpose(0, 2, 1, 3),
+                               vc.transpose(0, 2, 1, 3))  # (B,H,S,D) int8
+        else:
+            # bidirectional (BERT): paper-style row LUT softmax, materialized
+            ctx = _attn_rows_q8(qc, kc, vc, aq, cfg, None)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
     out = _lin(ctx, f["wo"], wb)
     return out, kc, vc
@@ -248,24 +256,26 @@ def _attn_decode(x_i8, f, cfg, cache, pos_offset):
     # per-slot ring-buffer write for SWA; plain per-slot append otherwise
     widx = (pos_vec % smax) if cfg.sliding_window else pos_vec
     upd = jax.vmap(lambda c, u, w: jax.lax.dynamic_update_slice(c, u, (w, 0, 0)))
-    k_cache = upd(cache["k"], kc, widx)
-    v_cache = upd(cache["v"], vc, widx)
+    with jax.named_scope("kv_write"):
+        k_cache = upd(cache["k"], kc, widx)
+        v_cache = upd(cache["v"], vc, widx)
     group = nh // nkv
     assert s == 1
     lengths = (jnp.minimum(pos_vec + 1, smax)    # valid ring prefix
                if cfg.sliding_window else pos_vec + 1)
     qg = qc.reshape(b, nkv, group, hd)                    # (B,kv,g,hd) int8
-    if ops.backend() == "pallas":
-        # TPU fast path: cache-native layout straight into the kernel (no
-        # per-step transpose of the whole cache), one KV stream per block
-        # shared by the whole q group, per-slot length masking inside.
-        from repro.kernels.decode_attention import decode_qattention
-        ctx = decode_qattention(
-            qg, k_cache, v_cache, lengths,
-            aq["M_idx"], aq["sh_idx"], _lut_q7(),
-            aq["inv_s_logit"], aq["out_scale"])           # (B,kv,g,hd) int8
-    else:
-        ctx = _gqa_decode_jnp(qg, k_cache, v_cache, lengths, aq)
+    with jax.named_scope("core"):
+        if ops.backend() == "pallas":
+            # TPU fast path: cache-native layout straight into the kernel
+            # (no per-step transpose of the whole cache), one KV stream per
+            # block shared by the whole q group, per-slot length masking.
+            from repro.kernels.decode_attention import decode_qattention
+            ctx = decode_qattention(
+                qg, k_cache, v_cache, lengths,
+                aq["M_idx"], aq["sh_idx"], _lut_q7(),
+                aq["inv_s_logit"], aq["out_scale"])       # (B,kv,g,hd) int8
+        else:
+            ctx = _gqa_decode_jnp(qg, k_cache, v_cache, lengths, aq)
     ctx = ctx.reshape(b, nh, s, hd)                       # == (B,H,1,hd)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
     out = _lin(ctx, f["wo"], cfg.quant.w_bits)
@@ -359,59 +369,62 @@ def _attn_decode_paged(x_i8, f, cfg, cache, pos_offset, block_tables,
         vc = _tp_slice(vc, tp_axis, nkv_loc, 2)
     else:
         assert nkv_loc == nkv, (nkv_loc, nkv)
-    # write-through-table: one (Hkv_loc, hd) row per slot into its own page
-    pg = jnp.take_along_axis(block_tables, (pos_vec // psize)[:, None],
-                             axis=1)[:, 0]                # (B,) page ids
-    row = pos_vec % psize
-    if kv4:
-        from repro.core import packing
-        # page scale from the FULL-head codes (rank-identical under TP — a
-        # sliced amax would let ranks disagree on the shared scale): the
-        # row opening a page (row == 0) sets a fresh scale from its own
-        # codes, later rows reuse the page's existing scale so previously
-        # written rows keep dequantizing to the same values
-        ks_fresh = jax.vmap(packing.kv_page_scale)(kc_full[:, 0])   # (B,)
-        vs_fresh = jax.vmap(packing.kv_page_scale)(vc_full[:, 0])
-        ks_pg = jnp.where(row == 0, ks_fresh, cache["ks"][pg])
-        vs_pg = jnp.where(row == 0, vs_fresh, cache["vs"][pg])
-        kq = packing.quantize_kv_page(kc[:, 0], ks_pg[:, None, None])
-        vq = packing.quantize_kv_page(vc[:, 0], vs_pg[:, None, None])
-        k_pool = cache["k"].at[pg, :, row].set(kq)
-        v_pool = cache["v"].at[pg, :, row].set(vq)
-        npool = {"k": k_pool, "v": v_pool,
-                 "ks": cache["ks"].at[pg].set(ks_pg),
-                 "vs": cache["vs"].at[pg].set(vs_pg)}
-    else:
-        k_pool = cache["k"].at[pg, :, row].set(kc[:, 0])
-        v_pool = cache["v"].at[pg, :, row].set(vc[:, 0])
-        npool = {"k": k_pool, "v": v_pool}
+    with jax.named_scope("kv_write"):
+        # write-through-table: one (Hkv_loc, hd) row per slot into its own page
+        pg = jnp.take_along_axis(block_tables, (pos_vec // psize)[:, None],
+                                 axis=1)[:, 0]                # (B,) page ids
+        row = pos_vec % psize
+        if kv4:
+            from repro.core import packing
+            # page scale from the FULL-head codes (rank-identical under TP — a
+            # sliced amax would let ranks disagree on the shared scale): the
+            # row opening a page (row == 0) sets a fresh scale from its own
+            # codes, later rows reuse the page's existing scale so previously
+            # written rows keep dequantizing to the same values
+            ks_fresh = jax.vmap(packing.kv_page_scale)(kc_full[:, 0])   # (B,)
+            vs_fresh = jax.vmap(packing.kv_page_scale)(vc_full[:, 0])
+            ks_pg = jnp.where(row == 0, ks_fresh, cache["ks"][pg])
+            vs_pg = jnp.where(row == 0, vs_fresh, cache["vs"][pg])
+            kq = packing.quantize_kv_page(kc[:, 0], ks_pg[:, None, None])
+            vq = packing.quantize_kv_page(vc[:, 0], vs_pg[:, None, None])
+            k_pool = cache["k"].at[pg, :, row].set(kq)
+            v_pool = cache["v"].at[pg, :, row].set(vq)
+            npool = {"k": k_pool, "v": v_pool,
+                     "ks": cache["ks"].at[pg].set(ks_pg),
+                     "vs": cache["vs"].at[pg].set(vs_pg)}
+        else:
+            k_pool = cache["k"].at[pg, :, row].set(kc[:, 0])
+            v_pool = cache["v"].at[pg, :, row].set(vc[:, 0])
+            npool = {"k": k_pool, "v": v_pool}
     lengths = pos_vec + 1
     qg = qc.reshape(b, nkv_loc, group, hd)                # (B,kv,g,hd) int8
-    if ops.backend() == "pallas":
-        if kv4:
-            from repro.kernels.decode_attention import \
-                paged_decode_qattention_q4
-            ctx = paged_decode_qattention_q4(
-                qg, k_pool, v_pool, npool["ks"], npool["vs"], block_tables,
-                lengths, aq["M_idx"], aq["sh_idx"], _lut_q7(),
-                aq["inv_s_logit"], aq["out_scale"])       # (B,kv,g,hd) int8
+    with jax.named_scope("core"):
+        if ops.backend() == "pallas":
+            if kv4:
+                from repro.kernels.decode_attention import \
+                    paged_decode_qattention_q4
+                ctx = paged_decode_qattention_q4(
+                    qg, k_pool, v_pool, npool["ks"], npool["vs"], block_tables,
+                    lengths, aq["M_idx"], aq["sh_idx"], _lut_q7(),
+                    aq["inv_s_logit"], aq["out_scale"])       # (B,kv,g,hd) int8
+            else:
+                from repro.kernels.decode_attention import \
+                    paged_decode_qattention
+                ctx = paged_decode_qattention(
+                    qg, k_pool, v_pool, block_tables, lengths,
+                    aq["M_idx"], aq["sh_idx"], _lut_q7(),
+                    aq["inv_s_logit"], aq["out_scale"])       # (B,kv,g,hd) int8
         else:
-            from repro.kernels.decode_attention import paged_decode_qattention
-            ctx = paged_decode_qattention(
-                qg, k_pool, v_pool, block_tables, lengths,
-                aq["M_idx"], aq["sh_idx"], _lut_q7(),
-                aq["inv_s_logit"], aq["out_scale"])       # (B,kv,g,hd) int8
-    else:
-        # gathered per-slot view (B, max_blocks*P, Hkv_loc, hd); masking
-        # makes the result bit-identical to the contiguous layout (int8)
-        # resp. to the kernel's fused per-tile dequant (kv4)
-        if kv4:
-            k_view = _dequant_paged_view(k_pool, npool["ks"], block_tables)
-            v_view = _dequant_paged_view(v_pool, npool["vs"], block_tables)
-        else:
-            k_view = _paged_view(k_pool, block_tables)
-            v_view = _paged_view(v_pool, block_tables)
-        ctx = _gqa_decode_jnp(qg, k_view, v_view, lengths, aq)
+            # gathered per-slot view (B, max_blocks*P, Hkv_loc, hd); masking
+            # makes the result bit-identical to the contiguous layout (int8)
+            # resp. to the kernel's fused per-tile dequant (kv4)
+            if kv4:
+                k_view = _dequant_paged_view(k_pool, npool["ks"], block_tables)
+                v_view = _dequant_paged_view(v_pool, npool["vs"], block_tables)
+            else:
+                k_view = _paged_view(k_pool, block_tables)
+                v_view = _paged_view(v_pool, block_tables)
+            ctx = _gqa_decode_jnp(qg, k_view, v_view, lengths, aq)
     if tp_axis is not None:
         # reassemble full heads (rank order == head order): int8 values
         # move, nothing is recomputed or re-rounded
@@ -463,37 +476,39 @@ def _attn_prefill_paged(x_i8, f, cfg, cache, pos, block_tables, pos0,
         vc = _tp_slice(vc, tp_axis, nkv_loc, 2)
     else:
         assert nkv_loc == nkv, (nkv_loc, nkv)
-    nb_s = s // psize
-    btab_slice = jax.lax.dynamic_slice_in_dim(block_tables, pos0 // psize,
-                                              nb_s, axis=1)
-    ncache = _paged_prefill_write(cache, kc, vc, btab_slice,
-                                  kc_full=kc_full, vc_full=vc_full)
+    with jax.named_scope("kv_write"):
+        nb_s = s // psize
+        btab_slice = jax.lax.dynamic_slice_in_dim(block_tables, pos0 // psize,
+                                                  nb_s, axis=1)
+        ncache = _paged_prefill_write(cache, kc, vc, btab_slice,
+                                      kc_full=kc_full, vc_full=vc_full)
     # kv4 drops the row-exact q8 identity claim by construction (a
     # decode-written page's scale comes from its first row, a prefill-
     # written page's from the whole page), so it always takes the q7
     # paged family — the quality-A/B contract, not the identity one
-    if row_exact and not kv4:
-        k_view = _paged_view(ncache["k"], block_tables)
-        v_view = _paged_view(ncache["v"], block_tables)
-        rows = k_view.shape[1]
-        qpos = pos0 + jnp.arange(s, dtype=jnp.int32)[:, None]
-        kpos = jnp.arange(rows, dtype=jnp.int32)[None, :]
-        ctx = _attn_rows_q8(qc, k_view, v_view, aq, cfg, kpos <= qpos)
-    elif kv4:
-        pos0_vec = jnp.broadcast_to(jnp.asarray(pos0, jnp.int32).reshape(-1),
-                                    (b,))
-        ctx = ops.paged_prefill_attention_q4(
-            qc.transpose(0, 2, 1, 3), ncache["k"], ncache["v"],
-            ncache["ks"], ncache["vs"], block_tables, pos0_vec,
-            aq["M_idx"], aq["sh_idx"], _lut_q7(),
-            aq["inv_s_logit"], aq["out_scale"])           # (B,H,S,hd) int8
-    else:
-        pos0_vec = jnp.broadcast_to(jnp.asarray(pos0, jnp.int32).reshape(-1),
-                                    (b,))
-        ctx = ops.paged_prefill_attention_q(
-            qc.transpose(0, 2, 1, 3), ncache["k"], ncache["v"],
-            block_tables, pos0_vec, aq["M_idx"], aq["sh_idx"], _lut_q7(),
-            aq["inv_s_logit"], aq["out_scale"])           # (B,H,S,hd) int8
+    with jax.named_scope("core"):
+        if row_exact and not kv4:
+            k_view = _paged_view(ncache["k"], block_tables)
+            v_view = _paged_view(ncache["v"], block_tables)
+            rows = k_view.shape[1]
+            qpos = pos0 + jnp.arange(s, dtype=jnp.int32)[:, None]
+            kpos = jnp.arange(rows, dtype=jnp.int32)[None, :]
+            ctx = _attn_rows_q8(qc, k_view, v_view, aq, cfg, kpos <= qpos)
+        elif kv4:
+            pos0_vec = jnp.broadcast_to(
+                jnp.asarray(pos0, jnp.int32).reshape(-1), (b,))
+            ctx = ops.paged_prefill_attention_q4(
+                qc.transpose(0, 2, 1, 3), ncache["k"], ncache["v"],
+                ncache["ks"], ncache["vs"], block_tables, pos0_vec,
+                aq["M_idx"], aq["sh_idx"], _lut_q7(),
+                aq["inv_s_logit"], aq["out_scale"])           # (B,H,S,hd) int8
+        else:
+            pos0_vec = jnp.broadcast_to(
+                jnp.asarray(pos0, jnp.int32).reshape(-1), (b,))
+            ctx = ops.paged_prefill_attention_q(
+                qc.transpose(0, 2, 1, 3), ncache["k"], ncache["v"],
+                block_tables, pos0_vec, aq["M_idx"], aq["sh_idx"], _lut_q7(),
+                aq["inv_s_logit"], aq["out_scale"])           # (B,H,S,hd) int8
     if tp_axis is not None:
         ctx = jax.lax.all_gather(ctx, tp_axis, axis=1, tiled=True)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
@@ -591,30 +606,32 @@ def _attn_verify_paged(x_i8, f, cfg, cache, pos_vec, block_tables, n_rows,
     # columns land in the trash page (the block table would already map
     # beyond-chain positions there, but padding must not touch the last
     # real page's rows either)
-    valid = jnp.arange(s, dtype=jnp.int32)[None, :] < n_rows[:, None]
-    pg = jnp.take_along_axis(block_tables, positions // psize, axis=1)
-    pg = jnp.where(valid, pg, 0)
-    row = jnp.where(valid, positions % psize, 0)
-    ncache = {"k": cache["k"].at[pg, :, row].set(kc),
-              "v": cache["v"].at[pg, :, row].set(vc)}
-    if row_exact:
-        # gathered chain view + per-slot causal frontier: row i of slot b
-        # attends rows [0, pos_vec[b] + i] — bit-identical to the decode
-        # step at that position (see _attn_rows_q8)
-        k_view = _paged_view(ncache["k"], block_tables)
-        v_view = _paged_view(ncache["v"], block_tables)
-        rows = k_view.shape[1]
-        kpos = jnp.arange(rows, dtype=jnp.int32)[None, None, :]
-        ctx = _attn_rows_q8(qc, k_view, v_view, aq, cfg,
-                            kpos <= positions[:, :, None])
-    else:
-        # the paged prefill kernel IS the verifier: per-slot pos0 rides the
-        # scalar-prefetch argument (its frontier math never needed a
-        # page-aligned start), blocks past a chain are causally dead
-        ctx = ops.paged_prefill_attention_q(
-            qc.transpose(0, 2, 1, 3), ncache["k"], ncache["v"],
-            block_tables, pos_vec, aq["M_idx"], aq["sh_idx"], _lut_q7(),
-            aq["inv_s_logit"], aq["out_scale"])           # (B,H,S,hd) int8
+    with jax.named_scope("kv_write"):
+        valid = jnp.arange(s, dtype=jnp.int32)[None, :] < n_rows[:, None]
+        pg = jnp.take_along_axis(block_tables, positions // psize, axis=1)
+        pg = jnp.where(valid, pg, 0)
+        row = jnp.where(valid, positions % psize, 0)
+        ncache = {"k": cache["k"].at[pg, :, row].set(kc),
+                  "v": cache["v"].at[pg, :, row].set(vc)}
+    with jax.named_scope("core"):
+        if row_exact:
+            # gathered chain view + per-slot causal frontier: row i of slot b
+            # attends rows [0, pos_vec[b] + i] — bit-identical to the decode
+            # step at that position (see _attn_rows_q8)
+            k_view = _paged_view(ncache["k"], block_tables)
+            v_view = _paged_view(ncache["v"], block_tables)
+            rows = k_view.shape[1]
+            kpos = jnp.arange(rows, dtype=jnp.int32)[None, None, :]
+            ctx = _attn_rows_q8(qc, k_view, v_view, aq, cfg,
+                                kpos <= positions[:, :, None])
+        else:
+            # the paged prefill kernel IS the verifier: per-slot pos0 rides the
+            # scalar-prefetch argument (its frontier math never needed a
+            # page-aligned start), blocks past a chain are causally dead
+            ctx = ops.paged_prefill_attention_q(
+                qc.transpose(0, 2, 1, 3), ncache["k"], ncache["v"],
+                block_tables, pos_vec, aq["M_idx"], aq["sh_idx"], _lut_q7(),
+                aq["inv_s_logit"], aq["out_scale"])           # (B,H,S,hd) int8
     if tp_axis is not None:
         ctx = jax.lax.all_gather(ctx, tp_axis, axis=1, tiled=True)
     ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, nh * hd)
@@ -628,17 +645,21 @@ def _mlp_int(x_i8, f, cfg):
     wb = cfg.quant.w_bits
     h = _ln(x_i8, f["ln2"], cfg)
     if cfg.act == "swiglu":
-        g = _lin(h, f["wg"], wb)
-        u = _lin(h, f["wu"], wb)
+        with jax.named_scope("up_gate"):
+            g = _lin(h, f["wg"], wb)
+            u = _lin(h, f["wu"], wb)
         g = _lut8(g, f["silu_lut"])
         prod = g.astype(jnp.int32) * u.astype(jnp.int32)       # int16-range
         hh = jnp.clip(fxp.rescale(prod, f["prod"]["M"], f["prod"]["sh"]),
                       -127, 127).astype(jnp.int8)
-        return _lin(hh, f["wd"], wb)
-    g = _lin(h, f["w1"], wb)
+        with jax.named_scope("down"):
+            return _lin(hh, f["wd"], wb)
+    with jax.named_scope("up_gate"):
+        g = _lin(h, f["w1"], wb)
     g = _lut8(g, f["gelu_lut"])
     g = _rescale_i8(g, f["gelu_rescale"])
-    return _lin(g, f["w2"], wb)
+    with jax.named_scope("down"):
+        return _lin(g, f["w2"], wb)
 
 
 def _moe_int(x_i8, f, cfg):
@@ -661,13 +682,15 @@ def _moe_int(x_i8, f, cfg):
 
     def expert_ffn(xe_i8, grp):
         def one(x1, wg, wu, wd):
-            g = _lin(x1, wg, wb)
-            u = _lin(x1, wu, wb)
+            with jax.named_scope("up_gate"):
+                g = _lin(x1, wg, wb)
+                u = _lin(x1, wu, wb)
             g = _lut8(g, grp["silu_lut"])
             prod = g.astype(jnp.int32) * u.astype(jnp.int32)
             hh = jnp.clip(fxp.rescale(prod, grp["prod"]["M"], grp["prod"]["sh"]),
                           -127, 127).astype(jnp.int8)
-            return _lin(hh, wd, wb)
+            with jax.named_scope("down"):
+                return _lin(hh, wd, wb)
         return jax.vmap(one)(xe_i8, grp["wg"], grp["wu"], grp["wd"])
 
     ye = expert_ffn(xe, fe)                                     # (E,C,d) int8
@@ -959,37 +982,42 @@ def serve_forward(
             f = f_rep[f"slot{i}"]
             cslot = None if cache_rep is None else cache_rep[f"slot{i}"]
             if mixer == "attn":
-                if mode == "decode":
-                    out, nc = (
-                        _attn_decode_paged(x_i8, f, cfg, cslot, pos_offset,
-                                           block_tables, tp_axis=tp_axis)
-                        if block_tables is not None
-                        else _attn_decode(x_i8, f, cfg, cslot, pos_offset))
-                elif mode == "verify":
-                    out, nc = _attn_verify_paged(
-                        x_i8, f, cfg, cslot, vpos, block_tables, verify_rows,
-                        row_exact=ops.backend() != "pallas", tp_axis=tp_axis)
-                else:
-                    # cached prefill matches the decode datapath per backend:
-                    # row-exact q8 softmax mirrors the jnp decode (bit-exact
-                    # continuation); on pallas both sides use the q7 flash
-                    # family instead (self-consistent, not bit-identical)
-                    row_exact = cslot is not None and ops.backend() != "pallas"
-                    if cslot is not None and block_tables is not None:
-                        # chunk (or one-shot / suffix-only) prefill written
-                        # and read through the block table
-                        out, nc = _attn_prefill_paged(
-                            x_i8, f, cfg, cslot, pos, block_tables, pos0,
-                            row_exact, tp_axis=tp_axis)
+                with jax.named_scope("attn"):
+                    if mode == "decode":
+                        out, nc = (
+                            _attn_decode_paged(x_i8, f, cfg, cslot, pos_offset,
+                                               block_tables, tp_axis=tp_axis)
+                            if block_tables is not None
+                            else _attn_decode(x_i8, f, cfg, cslot, pos_offset))
+                    elif mode == "verify":
+                        out, nc = _attn_verify_paged(
+                            x_i8, f, cfg, cslot, vpos, block_tables,
+                            verify_rows, row_exact=ops.backend() != "pallas",
+                            tp_axis=tp_axis)
                     else:
-                        out, kc, vc = _attn_prefill(x_i8, f, cfg, pos,
-                                                    row_exact=row_exact)
-                        # one-shot prefill into the contiguous stripe
-                        nc = (None if cslot is None else
-                              {"k": jax.lax.dynamic_update_slice(
-                                        cslot["k"], kc, (0, 0, 0, 0)),
-                               "v": jax.lax.dynamic_update_slice(
-                                        cslot["v"], vc, (0, 0, 0, 0))})
+                        # cached prefill matches the decode datapath per
+                        # backend: row-exact q8 softmax mirrors the jnp
+                        # decode (bit-exact continuation); on pallas both
+                        # sides use the q7 flash family instead
+                        # (self-consistent, not bit-identical)
+                        row_exact = (cslot is not None
+                                     and ops.backend() != "pallas")
+                        if cslot is not None and block_tables is not None:
+                            # chunk (or one-shot / suffix-only) prefill written
+                            # and read through the block table
+                            out, nc = _attn_prefill_paged(
+                                x_i8, f, cfg, cslot, pos, block_tables, pos0,
+                                row_exact, tp_axis=tp_axis)
+                        else:
+                            out, kc, vc = _attn_prefill(
+                                x_i8, f, cfg, pos, row_exact=row_exact)
+                            # one-shot prefill into the contiguous stripe
+                            with jax.named_scope("kv_write"):
+                                nc = (None if cslot is None else
+                                      {"k": jax.lax.dynamic_update_slice(
+                                          cslot["k"], kc, (0, 0, 0, 0)),
+                                       "v": jax.lax.dynamic_update_slice(
+                                          cslot["v"], vc, (0, 0, 0, 0))})
             elif mixer == "mamba":
                 out, nc = _mamba_int(x_i8, f, cfg,
                                      cslot if mode == "decode" else None)
@@ -999,10 +1027,12 @@ def serve_forward(
             new_cache_rep[f"slot{i}"] = nc if nc is not None else cslot
             x_i8 = _resid_add(x_i8, f["res_a"], out)
             if ffn == "dense":
-                out = _mlp_int(x_i8, f, cfg)
+                with jax.named_scope("mlp"):
+                    out = _mlp_int(x_i8, f, cfg)
                 x_i8 = _resid_add(x_i8, f["res_m"], out)
             elif ffn == "moe":
-                out = _moe_int(x_i8, f, cfg)
+                with jax.named_scope("mlp"):
+                    out = _moe_int(x_i8, f, cfg)
                 x_i8 = _resid_add(x_i8, f["res_m"], out)
         x_i8 = _rescale_i8(x_i8, f_rep["block_out_rescale"])
         return x_i8, new_cache_rep
@@ -1033,8 +1063,9 @@ def serve_forward(
                                   preferred_element_type=jnp.int32)
         return acc.astype(jnp.float32) * hw["inv_acc"]
 
-    logits = (jnp.stack([head_apply(jax.tree.map(lambda t: t[i], head))
-                         for i in range(cfg.n_lm_heads)], axis=1)
-              if cfg.n_lm_heads > 1 and not cfg.tied_embeddings
-              else head_apply(head))
+    with jax.named_scope("lm_head"):
+        logits = (jnp.stack([head_apply(jax.tree.map(lambda t: t[i], head))
+                             for i in range(cfg.n_lm_heads)], axis=1)
+                  if cfg.n_lm_heads > 1 and not cfg.tied_embeddings
+                  else head_apply(head))
     return logits, new_cache

@@ -119,6 +119,9 @@ from repro.serve import stats as stats_schema
 from repro.serve.scheduler import (BlockAllocator, Scheduler, SlotState,
                                    pages_needed)
 
+# host spans on the profiler's clock; every name is a key of stats.SPANS
+_span = jax.profiler.TraceAnnotation
+
 
 class RequestStatus(enum.Enum):
     """Explicit request lifecycle.  WAITING → PREFILL → DECODE → FINISHED
@@ -992,7 +995,10 @@ class Engine:
             logits, self.cache = self._prefill(
                 self.folded, self.cache, jnp.asarray(toks),
                 jnp.asarray(btab), jnp.int32(pos0))
-            last = np.asarray(logits[0, ntok - 1]) if final else None
+            last = None
+            if final:
+                with _span("serve.fetch", what="chunk"):
+                    last = np.asarray(logits[0, ntok - 1])
         else:
             assert pos0 == 0 and final, \
                 "contiguous layout prefills in one whole-prompt chunk"
@@ -1184,18 +1190,27 @@ class Engine:
         props = {b: p for b, p in props.items() if b in live}
         if not props:
             return None
-        toks = np.zeros((self.batch, self.spec_k + 1), np.int32)
-        nrows = np.ones((self.batch,), np.int32)
-        for b in active:
-            toks[b, 0] = self.sched.slots[b].last_token
-        for b, p in props.items():
-            toks[b, 1:1 + len(p)] = p
-            nrows[b] = 1 + len(p)
-        logits, self.cache = self._verify(
-            self.folded, self.cache, jnp.asarray(toks),
-            jnp.asarray(self.pos), jnp.asarray(self.block_tables),
-            jnp.asarray(nrows))
-        rows = np.asarray(logits)           # (B, spec_k+1, V)
+        with _span("serve.decode", rows=len(active)):
+            toks = np.zeros((self.batch, self.spec_k + 1), np.int32)
+            nrows = np.ones((self.batch,), np.int32)
+            for b in active:
+                toks[b, 0] = self.sched.slots[b].last_token
+            for b, p in props.items():
+                toks[b, 1:1 + len(p)] = p
+                nrows[b] = 1 + len(p)
+            logits, self.cache = self._verify(
+                self.folded, self.cache, jnp.asarray(toks),
+                jnp.asarray(self.pos), jnp.asarray(self.block_tables),
+                jnp.asarray(nrows))
+        with _span("serve.fetch", what="verify"):
+            rows = np.asarray(logits)       # (B, spec_k+1, V)
+        with _span("serve.pick"):
+            return self._accept(active, props, rows)
+
+    def _accept(self, active, props: Dict[int, List[int]], rows: np.ndarray
+                ) -> List[TokenEvent]:
+        """Phase 4 of :meth:`_spec_tick`: greedy acceptance over the
+        verify rows, emission and the speculative counters."""
         events: List[TokenEvent] = []
         n_emitted = 0
         for b in active:
@@ -1268,24 +1283,33 @@ class Engine:
 
         Returns this tick's :class:`TokenEvent` stream, in emission order.
         Every request's stream ends with exactly one ``final`` event; a
-        cancelled/shed request's final event carries ``token=None``."""
+        cancelled/shed request's final event carries ``token=None``.
+
+        The tick and its phases are ``jax.profiler.TraceAnnotation`` spans
+        named in ``stats.SPANS``: free while no profiler runs, and on the
+        device trace's clock while one does."""
         self.counters["ticks"] += 1
-        self._shed_expired()
-        events = self._events            # cancel/shed events queued so far
-        self._events = []
-        if self.prefix_tier is not None and self.sched.waiting:
-            self._adopt_from_tier()      # before admission: adopted pages
-            #                              surface as ordinary prefix hits
-        placed = self.sched.admit()
-        for _b, st in placed:
-            st.request.status = RequestStatus.PREFILL
-            if st.preemptions:          # a spilled request re-seated
-                self.counters["restores"] += 1
-        if self.layout == "paged" and self.sched.waiting \
-                and self.sched.n_free > 0:
-            # a request is waiting on PAGES, not slots: the stranded-
-            # capacity signal the overload bench A/Bs across policies
-            self.counters["pool_wait_ticks"] += 1
+        with _span("serve.tick", tick=self.counters["ticks"]):
+            return self._tick()
+
+    def _tick(self) -> List[TokenEvent]:
+        with _span("serve.admit"):
+            self._shed_expired()
+            events = self._events        # cancel/shed events queued so far
+            self._events = []
+            if self.prefix_tier is not None and self.sched.waiting:
+                self._adopt_from_tier()  # before admission: adopted pages
+                #                          surface as ordinary prefix hits
+            placed = self.sched.admit()
+            for _b, st in placed:
+                st.request.status = RequestStatus.PREFILL
+                if st.preemptions:      # a spilled request re-seated
+                    self.counters["restores"] += 1
+            if self.layout == "paged" and self.sched.waiting \
+                    and self.sched.n_free > 0:
+                # a request is waiting on PAGES, not slots: the stranded-
+                # capacity signal the overload bench A/Bs across policies
+                self.counters["pool_wait_ticks"] += 1
         n_decode = len(self.sched.decoding)
         used = 0
         chunked: set = set()
@@ -1299,12 +1323,15 @@ class Engine:
             # a final chunk hands the slot into this tick's decode batch:
             # charge its decode token so the budget stays a real cap
             used += ntok + (pos0 + ntok >= st.prompt_len)
-            events.extend(self._run_chunk(b, st, pos0, ntok))
+            with _span("serve.chunk", rid=st.rid, slot=b, pos0=pos0,
+                       ntok=ntok):
+                events.extend(self._run_chunk(b, st, pos0, ntok))
         for b in self.sched.prefilling:   # scheduler anti-starvation input
             st = self.sched.slots[b]
             st.starved_ticks = 0 if b in chunked else st.starved_ticks + 1
         if self.layout == "paged" and self.reserve_policy == "ondemand":
-            self._grow_decode_pages()     # may preempt victims
+            with _span("serve.grow"):
+                self._grow_decode_pages()  # may preempt victims
         active = self.sched.decoding
         if self.layout == "paged":
             self.counters["cache_pages_peak"] = self.alloc.peak_live
@@ -1317,28 +1344,31 @@ class Engine:
                 self.counters["cache_pages_peak"] = self.alloc.peak_live
                 return events
             # no proposals anywhere: fall through to plain decode
-        toks = np.zeros((self.batch, 1), np.int32)
-        for b in active:
-            toks[b, 0] = self.sched.slots[b].last_token
-        extra = ((jnp.asarray(self.block_tables),)
-                 if self.layout == "paged" else ())
-        logits, self.cache = self._decode(
-            self.folded, self.cache, jnp.asarray(toks),
-            jnp.asarray(self.pos), *extra)
-        rows = np.asarray(logits[:, -1])          # (B, V)
-        for b in active:
-            st = self.sched.slots[b]
-            req = st.request
-            self.pos[b] += 1
-            st.pos += 1
-            tok = self._pick_token(rows[b], req)
-            st.last_token = tok
-            st.emitted.append(tok)
-            if self._done(st):
-                self._finish(b)
-            events.append(TokenEvent(st.rid, tok, len(st.emitted) - 1,
-                                     req.status.terminal,
-                                     req.finish_reason))
+        with _span("serve.decode", rows=len(active)):
+            toks = np.zeros((self.batch, 1), np.int32)
+            for b in active:
+                toks[b, 0] = self.sched.slots[b].last_token
+            extra = ((jnp.asarray(self.block_tables),)
+                     if self.layout == "paged" else ())
+            logits, self.cache = self._decode(
+                self.folded, self.cache, jnp.asarray(toks),
+                jnp.asarray(self.pos), *extra)
+        with _span("serve.fetch", what="decode"):
+            rows = np.asarray(logits[:, -1])      # (B, V)
+        with _span("serve.pick"):
+            for b in active:
+                st = self.sched.slots[b]
+                req = st.request
+                self.pos[b] += 1
+                st.pos += 1
+                tok = self._pick_token(rows[b], req)
+                st.last_token = tok
+                st.emitted.append(tok)
+                if self._done(st):
+                    self._finish(b)
+                events.append(TokenEvent(st.rid, tok, len(st.emitted) - 1,
+                                         req.status.terminal,
+                                         req.finish_reason))
         self.counters["decode_steps"] += 1
         self.counters["decode_tokens"] += len(active)
         return events

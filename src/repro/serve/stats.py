@@ -117,6 +117,25 @@ ROUTER_COUNTERS: Dict[str, str] = {
                        "least-loaded (no replica held the chain)",
 }
 
+# --- Engine trace spans (jax.profiler.TraceAnnotation names) -------------
+# Not part of any stats payload (no schema version bump).  Each span is
+# opened on the host thread that calls poll(), so spans nest: every name
+# but serve.tick runs inside serve.tick.  kwargs are ints or short
+# constant strings already at hand; no span is opened per slot or token.
+SPANS: Dict[str, str] = {
+    "serve.tick": "one poll() (kwarg tick: the ticks counter)",
+    "serve.admit": "deadline shed, shared-tier adoption and sched.admit()",
+    "serve.chunk": "one prefill chunk forward and its handoff "
+                   "(kwargs rid, slot, pos0, ntok)",
+    "serve.grow": "on-demand decode page growth (preemption included)",
+    "serve.decode": "building decode tokens and block tables, then "
+                    "dispatching the decode or verify forward (kwarg rows)",
+    "serve.fetch": "a blocking device-to-host logits read "
+                   "(kwarg what: decode, verify or chunk)",
+    "serve.pick": "choosing tokens, finishing requests and building events "
+                  "after a decode or verify fetch",
+}
+
 _GAUGE_KEYS = frozenset(GAUGES)
 _PAGED_KEYS = frozenset(PAGED_GAUGES)
 _COUNTER_KEYS = frozenset(COUNTERS)
