@@ -120,10 +120,20 @@ def _dead_unclamped_decode_map(_bkv):
     return kv_map
 
 
-def _trash_paged_map(_psize):
-    def kv_map(bb, h, k, lens, btab):    # noqa: ARG001
-        return (btab[bb, k], h, 0, 0)    # dead k reads the trash page
+def _trash_paged_map(_psize, ppb, i):
+    def kv_map(bb, j, lens, btab):       # noqa: ARG001
+        k = min(j * ppb + i, btab.shape[1] - 1)
+        return (btab[bb, k], 0, 0, 0)    # dead k reads the trash page
     return kv_map
+
+
+def _overrun_paged_steps(psize, ppb):
+    def step_pages(bb, step, lens, btab):
+        live = (int(lens[bb]) + psize - 1) // psize
+        count = max(0, min(live + 1 - step * ppb, ppb))  # one page too many
+        return count, lambda i: btab[bb, min(step * ppb + i,
+                                             btab.shape[1] - 1)]
+    return step_pages
 
 
 FIXTURES: Dict[str, Fixture] = {
@@ -149,6 +159,11 @@ FIXTURES: Dict[str, Fixture] = {
         "IDXMAP-RANGE",
         lambda: pallas_lint.check_paged_decode_kv_map(
             _trash_paged_map, kernel="fixture:paged_trash")),
+    "idxmap_paged_overrun": Fixture(
+        "IDXMAP-RANGE",
+        lambda: pallas_lint.check_paged_decode_kv_map(
+            step_factory=_overrun_paged_steps,
+            kernel="fixture:paged_overrun")),
     # negative controls: a correct graph and a boundary-blessed pool cast
     "clean_int_graph": Fixture("", lambda: _audit(_clean)),
     "blessed_pool_cast": Fixture("", _run_blessed),

@@ -4,23 +4,27 @@ The kernels' correctness story leans on three structural claims that
 nothing machine-checked until now:
 
 ``IDXMAP-RANGE`` / ``IDXMAP-CLAMP``
-    The KV BlockSpec index maps clamp dead blocks in-range: for EVERY grid
-    point, over a battery of edge-case lengths / chunk origins / block
-    tables, the returned block coordinates must address inside the backing
-    array, and every *dead* step must re-address exactly the last live
-    block's page (that identity is why the pipeliner skips the DMA — an
+    The KV page addresses stay in range and dead blocks cost no copy: for
+    EVERY grid point, over a battery of edge-case lengths / chunk origins /
+    block tables, the returned block coordinates must address inside the
+    backing array, and every *dead* step must re-address the block already
+    resident (that identity is why the pipeliner skips the DMA — an
     out-of-range or merely-different address silently streams garbage or
-    wastes bandwidth).  The maps are module-level factories
+    wastes bandwidth).  The paged decode kernels' pages must besides be
+    the slot's own live pages, and the pages they copy themselves exactly
+    the live ones, once each.  The maps are module-level factories
     (``decode_kv_index_map`` / ``paged_kv_index_map`` /
-    ``prefill_kv_index_map``) precisely so this lint can evaluate them.
+    ``paged_step_pages`` / ``prefill_kv_index_map``) precisely so this
+    lint can evaluate them.
 
 ``VMEM-BUDGET``
     The per-grid-step VMEM working set implied by the BlockSpec geometry
     (double-buffered KV tiles + q/out tiles + LUT + scratch) must fit the
     shared ``kernels/hw_constants`` budget at the tile sizes
-    ``kernels/autotune`` actually picks — the tuner's quick filter only
-    models the KV tiles, so this is the check that scratch growth can't
-    sneak past it.
+    ``kernels/autotune`` actually picks, and the paged decode kernels'
+    two steps of pages at the run length they derive from the shapes —
+    the tuner's quick filter only models the KV tiles, so this is the
+    check that scratch growth can't sneak past it.
 
 ``SCALAR-PREFETCH``
     ``PrefetchScalarGridSpec(num_scalar_prefetch=N)`` makes the FIRST N
@@ -31,9 +35,11 @@ nothing machine-checked until now:
 
 ``SHARED-BODY``
     The int8 and int4-packed wrappers claim byte-identical datapaths: both
-    must reach the shared ``_decode_body`` / ``_prefill_body`` through the
-    AST call graph, and both paged wrappers must build their KV index map
-    from the one shared factory rather than a local re-derivation.
+    must reach the shared ``_paged_decode_body`` / ``_prefill_body``
+    through the AST call graph (and every decode kernel the one per-block
+    carry update, ``_kv_block_update``), and both paged wrappers must
+    build their page addresses from the shared factories rather than a
+    local re-derivation.
 """
 from __future__ import annotations
 
@@ -116,41 +122,102 @@ def _example_btab(b: int, nb: int, n_pages: int,
     return btab
 
 
+def _distinct_btab(b: int, nb: int, lens: np.ndarray,
+                   psize: int) -> np.ndarray:
+    """Each slot's live blocks on distinct, non-monotone pages (no page
+    shared between slots, so a page of the wrong slot cannot pass), the
+    trash page 0 beyond its chain."""
+    btab = np.zeros((b, nb), np.int32)
+    for bb in range(b):
+        for k in range(-(-int(lens[bb]) // psize)):
+            btab[bb, k] = 1 + (bb * nb + k) * 7 % (b * nb)
+    return btab
+
+
 def check_paged_decode_kv_map(map_factory: Callable = DA.paged_kv_index_map,
-                              *, b: int = 3, hkv: int = 2, nb: int = 4,
-                              psize: int = 16, n_pages: int = 13,
+                              step_factory: Callable = DA.paged_step_pages,
+                              *, b: int = 4, nb: int = 11, psize: int = 16,
+                              ppb: int = 4,
                               kernel: str = "paged_decode_qattention",
                               ) -> List[Violation]:
-    """Paged decode map: the returned page must be a page of the slot's
-    own table row (in particular < n_pages), and dead logical blocks must
-    re-address the last live page."""
+    """Both page-address helpers of the paged decode kernels, over slots of
+    edge-case lengths side by side in one table:
+
+    * the pages a step copies itself (``step_factory``): each a live block
+      of the slot's own table row at its own offset, inside the pool; a
+      step past the slot's length copies nothing; every live block is
+      copied exactly once;
+    * the page block operands (``map_factory``): every page addressed is
+      one of the slot's own live pages inside the pool (its first table
+      entry for an empty slot); a live block addresses exactly its page;
+      past the slot's length an operand keeps the page it already holds,
+      so no copy is issued."""
     out: List[Violation] = []
-    kv_map = map_factory(psize)
-    smax = nb * psize
-    for lens_val in (0, 1, psize - 1, psize, psize + 1, smax - 1, smax):
-        live_blocks = max(-(-lens_val // psize), 1)
-        btab = _example_btab(b, nb, n_pages, live_blocks)
-        lens = np.full((b,), lens_val, np.int32)
-        last_live = max((lens_val - 1) // psize, 0)
-        for bb, h, k in itertools.product(range(b), range(hkv), range(nb)):
-            pg, h_o, r0, r1 = (int(x) for x in kv_map(bb, h, k, lens, btab))
-            if not (0 <= pg < n_pages and r0 == 0 and h_o == h and r1 == 0):
+    n_pages = b * nb + 1
+    nsteps = -(-nb // ppb)
+    steps = step_factory(psize, ppb)
+    maps = [map_factory(psize, ppb, i) for i in range(ppb)]
+    battery = (0, 1, psize - 1, psize, psize + 1, ppb * psize,
+               ppb * psize + 1, nb * psize - 1, nb * psize)
+    for rot in range(len(battery)):
+        lens = np.asarray([battery[(rot + bb) % len(battery)]
+                           for bb in range(b)], np.int32)
+        btab = _distinct_btab(b, nb, lens, psize)
+        for bb in range(b):
+            live = -(-int(lens[bb]) // psize)
+            own = {int(btab[bb, k]) for k in range(max(live, 1))}
+            copied = []
+            for j in range(nsteps):
+                count, page = steps(bb, j, lens, btab)
+                count = int(count)
+                if j * ppb >= live and count:
+                    out.append(_violation(
+                        "IDXMAP-CLAMP", kernel,
+                        f"len={lens[bb]} step {j} past the slot's length "
+                        f"copies {count} page(s)"))
+                for i in range(count):
+                    blk = j * ppb + i
+                    try:
+                        pg = int(page(i))
+                    except IndexError:            # read past the table row
+                        pg = -1
+                    copied.append(blk)
+                    if not (0 <= pg < n_pages and blk < live
+                            and pg == int(btab[bb, blk])):
+                        out.append(_violation(
+                            "IDXMAP-RANGE", kernel,
+                            f"len={lens[bb]} step {j} copies page {pg} as "
+                            f"block {blk}: not the slot's own live page "
+                            f"of a pool of {n_pages}"))
+            if copied != list(range(live)):
                 out.append(_violation(
                     "IDXMAP-RANGE", kernel,
-                    f"len={lens_val} grid=({bb},{h},{k}) -> page {pg} "
-                    f"outside pool of {n_pages}"))
-            elif pg != int(btab[bb, min(k, last_live)]):
-                out.append(_violation(
-                    "IDXMAP-RANGE", kernel,
-                    f"len={lens_val} grid=({bb},{h},{k}) -> page {pg} is "
-                    f"not the slot's own page "
-                    f"{int(btab[bb, min(k, last_live)])}"))
-            elif k * psize >= lens_val and pg != int(btab[bb, last_live]):
-                out.append(_violation(
-                    "IDXMAP-CLAMP", kernel,
-                    f"dead step k={k} (len={lens_val}) addresses page "
-                    f"{pg}, not the last live page "
-                    f"{int(btab[bb, last_live])}"))
+                    f"len={lens[bb]} copies blocks {copied}, not each of "
+                    f"the {live} live blocks once in order"))
+            for i, kv_map in enumerate(maps):
+                held = None
+                for j in range(nsteps):
+                    pg, *rest = (int(x) for x in kv_map(bb, j, lens, btab))
+                    blk = j * ppb + i
+                    if not (0 <= pg < n_pages and rest == [0, 0, 0]
+                            and pg in own):
+                        out.append(_violation(
+                            "IDXMAP-RANGE", kernel,
+                            f"len={lens[bb]} grid=({bb},{j}) operand {i} -> "
+                            f"page {pg}: not one of the slot's own live "
+                            f"pages of a pool of {n_pages}"))
+                    elif blk < live and pg != int(btab[bb, blk]):
+                        out.append(_violation(
+                            "IDXMAP-RANGE", kernel,
+                            f"len={lens[bb]} grid=({bb},{j}) operand {i} -> "
+                            f"page {pg}, live block {blk} is on page "
+                            f"{int(btab[bb, blk])}"))
+                    elif blk >= live and held is not None and pg != held:
+                        out.append(_violation(
+                            "IDXMAP-CLAMP", kernel,
+                            f"dead step {j} (len={lens[bb]}) operand {i} "
+                            f"addresses page {pg}, not the resident {held}"))
+                    held = pg
     return out
 
 
@@ -212,6 +279,21 @@ def _decode_tile_bytes(g: int, d: int, kv_tile_rows: int,
     return kv + q_out + LUT_BYTES + scratch
 
 
+def _paged_decode_tile_bytes(hkv: int, g: int, hd: int, page: int, nb: int,
+                             kv_bits: int):
+    """(pages per step, VMEM working set of one paged decode grid step):
+    the K and V pages of two steps (one computing, one arriving), q and
+    out blocks double-buffered, the LUT, and the three carries."""
+    row = hd // 2 if kv_bits == 4 else hd
+    page_bytes = DA.page_vmem_bytes((1, hkv, page, row), 1)
+    ppb = DA.pages_per_step(page_bytes, nb)
+    rows, lanes = hkv * g, -(-hd // 128) * 128
+    kv = 2 * 2 * ppb * page_bytes
+    q_out = 2 * 2 * rows * lanes
+    scratch = 2 * rows * 128 * 4 + rows * lanes * 4
+    return ppb, kv + q_out + LUT_BYTES + scratch
+
+
 def _prefill_tile_bytes(bq: int, d: int, psize: int, kv_bits: int) -> int:
     kv_row = d // 2 if kv_bits == 4 else d
     kv = 2 * 2 * psize * kv_row
@@ -226,6 +308,16 @@ _DECODE_SHAPES = (
     ("smoke", dict(smax=64, batch_slots=4, hkv=4, hd=32, kv_bits=8)),
     ("large", dict(smax=4096, batch_slots=64, hkv=8, hd=128, kv_bits=8)),
     ("large_kv4", dict(smax=4096, batch_slots=64, hkv=8, hd=128, kv_bits=4)),
+)
+# the benchmark cells' paged decode shapes, the int4 pool, and the local
+# head slice of a four-way tensor-parallel qwen3-4b
+_PAGED_DECODE_SHAPES = (
+    ("qwen3-4b.decode", dict(hkv=8, g=4, hd=128, page=16, nb=96, kv_bits=8)),
+    ("qwen3-4b.decode_kv4", dict(hkv=8, g=4, hd=128, page=16, nb=96,
+                                 kv_bits=4)),
+    ("stablelm-1.6b.longctx", dict(hkv=32, g=1, hd=64, page=16, nb=256,
+                                   kv_bits=8)),
+    ("qwen3-4b.tp4", dict(hkv=2, g=4, hd=128, page=16, nb=96, kv_bits=8)),
 )
 _PREFILL_SHAPES = (
     ("smoke", dict(sq=32, batch_slots=4, page_size=16, hkv=4, hd=32,
@@ -254,6 +346,14 @@ def check_vmem_budgets() -> List[Violation]:
                 f"bkv={bkv} working set {used}B exceeds "
                 f"{int(VMEM_BUDGET * VMEM_FILL)}B "
                 f"(VMEM_BUDGET*VMEM_FILL) at {kw}"))
+    for tag, kw in _PAGED_DECODE_SHAPES:
+        ppb, used = _paged_decode_tile_bytes(**kw)
+        if used > VMEM_BUDGET * VMEM_FILL:
+            out.append(_violation(
+                "VMEM-BUDGET", f"paged_decode[{tag}]",
+                f"ppb={ppb} working set {used}B exceeds "
+                f"{int(VMEM_BUDGET * VMEM_FILL)}B "
+                f"(VMEM_BUDGET*VMEM_FILL) at {kw}"))
     for tag, kw in _PREFILL_SHAPES:
         bq = autotune.prefill_bq(kw["sq"], batch_slots=kw["batch_slots"],
                                  page_size=kw["page_size"], hkv=kw["hkv"],
@@ -276,28 +376,30 @@ def check_vmem_budgets() -> List[Violation]:
 # kernel -> (module, expected scalar operand names, in pallas_call order)
 SCALAR_PREFETCH_ORDER = {
     "decode_qattention": (DA, ("lengths",)),
-    "paged_decode_qattention": (DA, ("lengths", "block_tables")),
-    "paged_decode_qattention_q4": (DA, ("lengths", "block_tables")),
+    "_paged_call": (DA, ("lengths", "block_tables")),   # both paged wrappers
     "paged_prefill_qattention": (PA, ("pos0", "block_tables")),
     "paged_prefill_qattention_q4": (PA, ("pos0", "block_tables")),
 }
 
-# wrapper kernel fn -> (module, shared body it must reach transitively)
+# kernel fn -> (module, shared body it must reach transitively)
 SHARED_BODY = {
-    "_decode_kernel": (DA, "_decode_body"),
-    "_paged_decode_kernel": (DA, "_decode_body"),
-    "_paged_decode_q4_kernel": (DA, "_decode_body"),
+    "_decode_kernel": (DA, "_kv_block_update"),
+    "_paged_decode_kernel": (DA, "_paged_decode_body"),
+    "_paged_decode_q4_kernel": (DA, "_paged_decode_body"),
+    "_paged_decode_body": (DA, "_kv_block_update"),
     "_paged_prefill_kernel": (PA, "_prefill_body"),
     "_paged_prefill_q4_kernel": (PA, "_prefill_body"),
 }
 
-# public wrapper -> (module, the index-map factory it must use)
+# public wrapper -> (module, the page-address factories it must use)
 INDEX_MAP_FACTORY = {
-    "decode_qattention": (DA, "decode_kv_index_map"),
-    "paged_decode_qattention": (DA, "paged_kv_index_map"),
-    "paged_decode_qattention_q4": (DA, "paged_kv_index_map"),
-    "paged_prefill_qattention": (PA, "prefill_kv_index_map"),
-    "paged_prefill_qattention_q4": (PA, "prefill_kv_index_map"),
+    "decode_qattention": (DA, ("decode_kv_index_map",)),
+    "paged_decode_qattention": (DA, ("paged_step_pages",
+                                     "paged_kv_index_map")),
+    "paged_decode_qattention_q4": (DA, ("paged_step_pages",
+                                        "paged_kv_index_map")),
+    "paged_prefill_qattention": (PA, ("prefill_kv_index_map",)),
+    "paged_prefill_qattention_q4": (PA, ("prefill_kv_index_map",)),
 }
 
 _mod_ast_cache: Dict[str, ast.Module] = {}
@@ -406,19 +508,20 @@ def check_shared_body() -> List[Violation]:
                 "SHARED-BODY", fn_name,
                 f"does not dispatch into the shared `{body}` — the "
                 "int8/int4 byte-identity claim no longer holds"))
-    for fn_name, (mod, factory) in INDEX_MAP_FACTORY.items():
+    for fn_name, (mod, factories) in INDEX_MAP_FACTORY.items():
         fd = _find_funcdef(mod, fn_name)
-        used = fd is not None and any(
-            isinstance(n, ast.Call) and _call_name(n) == factory
-            for n in ast.walk(fd))
         local = fd is not None and any(
             isinstance(n, ast.FunctionDef) and n.name == "kv_map"
             for n in ast.walk(fd))
-        if not used or local:
-            out.append(_violation(
-                "SHARED-BODY", fn_name,
-                f"KV index map must come from the shared `{factory}` "
-                "factory (no local kv_map re-derivations)"))
+        for factory in factories:
+            used = fd is not None and any(
+                isinstance(n, ast.Call) and _call_name(n) == factory
+                for n in ast.walk(fd))
+            if not used or local:
+                out.append(_violation(
+                    "SHARED-BODY", fn_name,
+                    f"KV page addresses must come from the shared "
+                    f"`{factory}` factory (no local kv_map re-derivations)"))
     return out
 
 

@@ -154,7 +154,8 @@ def paged_decode_qattention_ref(
     per step gathered through the block table, with the kernel's exact
     accumulation order (int32 scores, Q0.7 LUT numerators, fp32 running
     max-rescale / denominator / output carry).  Because every operation and
-    its order match ``_decode_kernel``, the Pallas kernel is BIT-EXACT
+    its order match ``_kv_block_update``, applied page by page in table
+    order, the Pallas kernel is BIT-EXACT
     against this oracle for any page count — unlike the contiguous kernel,
     whose oracle is the row-wise ``decode_qattention_ref`` (exact only when
     one block covers the row)."""
@@ -280,8 +281,7 @@ def paged_decode_qattention_q4_ref(q_i8, k_pool_u8, v_pool_u8, k_scale,
     fuses per tile) and delegates to the int8 block-online oracle.  This is
     an exact identity with the kernel's in-VMEM dequant: every page the
     kernel touches dequantizes to the same int8 codes this full view holds,
-    and pages it never reads (dead blocks re-address already-live pages)
-    contribute nothing either way."""
+    and pages past a slot's length contribute nothing either way."""
     k_pool = packing.dequantize_kv_pool(k_pool_u8, k_scale)
     v_pool = packing.dequantize_kv_pool(v_pool_u8, v_scale)
     return paged_decode_qattention_ref(q_i8, k_pool, v_pool, block_tables,

@@ -17,6 +17,7 @@ from repro.core import packing
 from repro.core import qsoftmax as qs
 from repro.kernels import ops
 from repro.kernels import ref as R
+from paged_cases import PAGE_RUN_CASES, page_run_lengths
 
 
 # --- packing round-trips -------------------------------------------------------
@@ -120,18 +121,23 @@ def _paged_inputs(b, hkv, g, d, psize, n_pages, nb, lengths, seed=31):
     return q, kp, vp, btab, M, sh, s_logit
 
 
-@pytest.mark.parametrize("psize,lengths", [
-    (64, [1, 37, 64]),          # one page covers every slot
-    (16, [1, 23, 48]),          # cross-page fp32 carry
-    (8, [5, 17, 40]),           # many ragged pages
-])
-def test_paged_decode_q4_bit_exact_vs_oracle(psize, lengths):
+@pytest.mark.parametrize("psize,lengths,geom", [
+    pytest.param(64, [1, 37, 64], None, id="64-lengths0"),   # one page a slot
+    pytest.param(16, [1, 23, 48], None, id="16-lengths1"),   # cross-page carry
+    pytest.param(8, [5, 17, 40], None, id="8-lengths2"),     # ragged pages
+] + PAGE_RUN_CASES)
+def test_paged_decode_q4_bit_exact_vs_oracle(psize, lengths, geom):
     """Fused-dequant paged decode kernel vs the q4 oracle (whole-pool
-    dequant + int8 block-online oracle): BIT-EXACT for any page count."""
+    dequant + int8 block-online oracle): BIT-EXACT for any page count,
+    over the same page runs and slot mixes as the int8 kernel."""
     from repro.kernels.decode_attention import paged_decode_qattention_q4
 
-    b, hkv, g, d = 3, 2, 4, 64
-    nb = 64 // psize
+    if geom is None:
+        b, (hkv, g, d), nb = 3, (2, 4, 64), 64 // psize
+    else:
+        hkv, g, d, psize, nb = geom
+        lengths = page_run_lengths(geom, lengths, row=d // 2)
+        b = len(lengths)
     n_pages = b * nb + 1
     q, kp, vp, btab, M, sh, s_logit = _paged_inputs(
         b, hkv, g, d, psize, n_pages, nb, lengths)

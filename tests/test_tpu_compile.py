@@ -98,19 +98,34 @@ def _attn_tail(sds):
             sds((1,), jnp.float32))
 
 
-@pytest.mark.parametrize("kv_bits", [8, 4])
-def test_paged_decode_compiles(sds, kv_bits):
+# the benchmark cells' paged decode shapes (slots, kv heads, group, head
+# dim, pages, table length): qwen3-4b.decode and stablelm-1.6b.longctx
+DECODE_CELL = (64, NKV, G, HD, 4097, 96)
+LONGCTX_CELL = (8, 32, 1, 64, 2049, 256)
+
+
+@pytest.mark.parametrize("kv_bits,shape", [
+    pytest.param(8, None, id="8"),
+    pytest.param(4, None, id="4"),
+    pytest.param(8, DECODE_CELL, id="8-decode_cell"),
+    pytest.param(4, DECODE_CELL, id="4-decode_cell"),
+    pytest.param(8, LONGCTX_CELL, id="8-longctx_cell"),
+    pytest.param(4, LONGCTX_CELL, id="4-longctx_cell"),
+])
+def test_paged_decode_compiles(sds, kv_bits, shape):
     from repro.kernels import decode_attention as DA
-    q = sds((SLOTS, NKV, G, HD), jnp.int8)
-    btab, lens = sds((SLOTS, NB), jnp.int32), sds((SLOTS,), jnp.int32)
+    slots, nkv, g, hd, n_pages, nb = shape or (SLOTS, NKV, G, HD, N_PAGES,
+                                                NB)
+    q = sds((slots, nkv, g, hd), jnp.int8)
+    btab, lens = sds((slots, nb), jnp.int32), sds((slots,), jnp.int32)
     if kv_bits == 8:
-        pool = sds((N_PAGES, NKV, PAGE, HD), jnp.int8)
+        pool = sds((n_pages, nkv, PAGE, hd), jnp.int8)
         _compile(functools.partial(DA.paged_decode_qattention,
                                    interpret=False),
                  q, pool, pool, btab, lens, *_attn_tail(sds))
     else:
-        pool = sds((N_PAGES, NKV, PAGE, HD // 2), jnp.uint8)
-        scale = sds((N_PAGES,), jnp.float32)
+        pool = sds((n_pages, nkv, PAGE, hd // 2), jnp.uint8)
+        scale = sds((n_pages,), jnp.float32)
         _compile(functools.partial(DA.paged_decode_qattention_q4,
                                    interpret=False),
                  q, pool, pool, scale, scale, btab, lens, *_attn_tail(sds))
