@@ -2,18 +2,17 @@
 
 After the window, a sample of the requests the timed engine finished,
 drawn from the seed and always holding the longest, is scored by the plain
-reference (``reference.py``) over each prompt with its served tokens.  The
-number compared is the widest gap, over every served token of the sample,
-by which the served token's reference logit lies below the reference's best
-logit at that position.  Greedy serving of a correct W4A8 program keeps it
-small; a token altered where it is produced, a wrong cache row, or int4
-arithmetic (the control) opens it.
+reference of the configuration's architecture (``arch/<arch>.py``
+``score``) over each prompt with its served tokens.  The number compared is
+the mean gap, over every served token of the sample, by which the served
+token's reference logit lies below the reference's best logit at that
+position.  Greedy serving of a correct W4A8 program keeps it small; a token
+altered where it is produced, a wrong cache row, or int4 arithmetic (the
+control) opens it.
 """
 from __future__ import annotations
 
 import numpy as np
-
-from chipbench import reference as R
 
 
 def pad_to(n: int, mult: int) -> int:
@@ -34,36 +33,38 @@ def sample_requests(done: list, n: int, seed: int) -> list:
     return [done[i] for i in [order[0], *pick]]
 
 
-def score_requests(params, m: dict, amax, reqs: list, length: int,
-                   ctrl_amax=None) -> dict:
+def score_requests(reqs: list, length: int, block: int, score_one,
+                   control: bool = False) -> dict:
     """Gaps over every served token of ``reqs``: ``program`` (the served
     token's reference logit below the reference's best) and, with
-    ``ctrl_amax``, ``control`` (the same for the token the int4 control
-    ranks first at that position).  Every sequence is padded to ``length``
-    (the cell's ``max_len``), so one compiled reference serves the cell."""
+    ``control``, ``control`` (the same for the token the control ranks
+    first at that position).  Each request is teacher-forced as one
+    sequence padded to ``length`` (the cell's ``max_len``) rounded up to
+    ``block``, so one compiled reference serves the cell:
+    ``score_one(tokens, targets)`` returns per position the reference's best
+    logit, its logit of ``targets`` (the token served after the position)
+    and its logit of the control's first choice."""
     import jax.numpy as jnp
 
-    items = R.model_items(m)
     prog, ctrl_gaps = [], []
     for r in reqs:
         prompt = np.asarray(r["prompt"], np.int64)
         served = np.asarray(r["out"], np.int64)
         seq = np.concatenate([prompt, served[:-1]])
         n = len(seq)
-        tokens = np.zeros(pad_to(max(n, length), R.QBLOCK), np.int32)
+        tokens = np.zeros(pad_to(max(n, length), block), np.int32)
         tokens[:n] = seq
         targets = np.zeros_like(tokens)
         first = len(prompt) - 1            # row that predicts served[0]
         targets[first:first + len(served)] = served
-        best, got, ctrl = (np.asarray(x) for x in R.score(
-            params, items, jnp.asarray(tokens), jnp.asarray(targets), amax,
-            8, ctrl_amax))
+        best, got, ctrl = (np.asarray(x) for x in score_one(
+            jnp.asarray(tokens), jnp.asarray(targets)))
         rows = slice(first, first + len(served))
         prog.append(best[rows] - got[rows])
-        if ctrl_amax is not None:
+        if control:
             ctrl_gaps.append(best[rows] - ctrl[rows])
     out = {"program": gap_stats(prog)}
-    if ctrl_amax is not None:
+    if control:
         out["control"] = gap_stats(ctrl_gaps)
     return out
 

@@ -2,9 +2,10 @@
 """Readings that the ``correct`` limits are set from (not part of a run).
 
 For each seed, in one process that holds the chip: one run of the cell
-at its own sizes and load (a short window), then the plain reference
-scores the same sample a run scores, and the int4 control (the reference
-with every activation site at int4) reads, at each position of the same
+at its own sizes and load (a short window), then the architecture
+module's plain reference (``arch/<arch>.py`` ``score``) scores the same
+sample a run scores, and its control (for ``dense``: the reference with
+every activation site at int4) reads, at each position of the same
 prompts and served tokens, the reference's gap of the token it ranks
 first.  One JSON line per seed on standard output.
 

@@ -7,15 +7,19 @@
 A cell (``BENCHMARK.json`` ``workloads``) names a configuration
 (``chipbench/configs/<config>.json``), a traffic mix
 (``chipbench/traffic/<mix>.json``) and its engine settings
-(``chipbench/cells/<cell>.json``); per-layer metrics are readers
+(``chipbench/cells/<cell>.json``); the configuration's ``"arch"`` names
+its architecture module ``chipbench/arch/<arch>.py`` (the program's
+configuration, set-up, plain reference and work counts: see
+``arch/dense.py``); per-layer metrics are readers
 ``chipbench/metrics/<metric>.py``.  Everything is found by name.
 
 One run, in one process that holds the cell's chips:
 
 1. refuse without a TPU (exit 2, no result line);
 2. turn on JAX's persistent compilation cache inside the checkout;
-3. make the float weights from the seed on the device, then the program's
-   calibration forward and integer fold; the float weights are freed;
+3. the architecture module makes the weights from the seed on the device,
+   runs the program's calibration forward and integer fold, and frees
+   the float weights;
 4. build the engine from the cell's settings;
 5. warm the decode graph and every prefill chunk length the mix can
    produce, through ``Engine.submit``/``poll``;
@@ -75,13 +79,15 @@ def load_spec(workload: str, root: Path = ROOT) -> dict:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json; known: "
                        f"{[x['name'] for x in bench['workloads']]}") from None
     c = next(x for x in bench["configs"] if x["name"] == w["config"])
+    config = json.loads((root / c["file"]).read_text())
 
     def applies(metric):
         return workload in metric.get("workloads", [workload])
 
     return {
         "workload": w,
-        "config": json.loads((root / c["file"]).read_text()),
+        "config": config,
+        "arch": load_arch(config, root / c["file"], root),
         "mix": TR.load_mix(w["traffic"], root / "chipbench"),
         "cell": json.loads(
             (root / "chipbench" / "cells" / f"{workload}.json").read_text()),
@@ -90,13 +96,28 @@ def load_spec(workload: str, root: Path = ROOT) -> dict:
     }
 
 
-def load_reader(name: str, root: Path = HERE):
-    path = root / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "chipbench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+def _load_module(kind: str, name: str, path: Path):
+    mod_name = f"chipbench_{kind}_" + name.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(mod_name, path)
     mod = importlib.util.module_from_spec(spec)
+    sys.modules[mod_name] = mod     # a dataclass looks its module up there
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_arch(config: dict, path: Path, root: Path = ROOT):
+    """The architecture module ``chipbench/arch/<arch>.py`` that the
+    configuration read from ``path`` names."""
+    if "arch" not in config:
+        raise KeyError(f"{path}: the configuration names no \"arch\" "
+                       f"(a module chipbench/arch/<arch>.py)")
+    name = config["arch"]
+    return _load_module("arch", name,
+                        root / "chipbench" / "arch" / f"{name}.py")
+
+
+def load_reader(name: str, root: Path = HERE):
+    return _load_module("metric", name, root / "metrics" / f"{name}.py").read
 
 
 # --- the run's bookkeeping ---------------------------------------------------
@@ -199,26 +220,15 @@ def counters_of(server) -> dict:
 
 # --- phases -----------------------------------------------------------------
 
-def build_engine(spec, seed, devices, clock):
-    """Weights -> calibration -> fold -> engine.  Returns (server, folded
-    stats)."""
-    import jax
-
-    from chipbench import model as M
+def build_engine(spec, seed, devices):
+    """The architecture's folded parameters -> engine.  Returns (server,
+    program config)."""
     from repro.serve.engine import Engine, EngineConfig
 
-    m, cell = spec["config"]["model"], spec["cell"]
-    cfg = M.program_config(m)
-    t = clock()
-    params = M.make_params(m, seed)
-    jax.block_until_ready(params)
-    log(f"setup: weights {clock() - t:.2f} s")
-    t = clock()
-    folded = M.fold_on_device(cfg, params, M.calib_tokens(m, seed))
-    jax.block_until_ready(folded)
-    del params
+    m, cell, arch = spec["config"]["model"], spec["cell"], spec["arch"]
+    cfg = arch.program_config(m)
+    folded = arch.folded(cfg, m, seed)
     gc.collect()
-    log(f"setup: calibration + fold {clock() - t:.2f} s")
     e = cell["engine"]
     ecfg = EngineConfig(batch_slots=e["slots"], max_len=e["max_len"],
                         page_size=e["page"], n_pages=e["n_pages"],
@@ -327,7 +337,7 @@ def measure(spec, seed: int, seconds: float, trace: bool, devices,
     jax.monitoring.register_event_duration_secs_listener(on_event)
 
     m, cell, mix = spec["config"]["model"], spec["cell"], spec["mix"]
-    eng, _ = build_engine(spec, seed, devices, clock)
+    eng, _ = build_engine(spec, seed, devices)
     server = eng
     warm(server, spec, m["vocab_size"], clock, seed)
     tr = TR.Traffic(mix, seed, m["vocab_size"])
@@ -439,38 +449,39 @@ def end_to_end(run: dict):
 
 def check_outputs(spec, seed, run, sample_n, control=False) -> dict:
     """Free the engine, then score a sample of finished requests against the
-    reference.  Returns the numbers compared.  ``control`` also reads the
-    int4 control's widest gap on the same sample (``control.py``; the
+    architecture's reference.  Returns the numbers compared.  ``control``
+    also reads the control's gaps on the same sample (``control.py``; the
     benchmark's own runs do not)."""
-    import jax
-
     from chipbench import check as C
-    from chipbench import model as M
-    from chipbench import reference as R
 
     done = [{"prompt": r.prompt, "out": np.asarray(r.toks)}
             for r in run["drv"].completed
             if r.reason == "length" and r.toks]
     run["server"] = run["eng"] = run["drv"].server = None
     gc.collect()
-    m = spec["config"]["model"]
-    params = M.make_params(m, seed)
-    amax = R.calibrate(params, R.model_items(m),
-                       jax.numpy.asarray(M.calib_tokens(m, seed)), 8)
-    ctrl = (R.calibrate(params, R.model_items(m),
-                        jax.numpy.asarray(M.calib_tokens(m, seed)), 4)
-            if control else None)
     sample = C.sample_requests(done, sample_n, seed)
-    got = C.score_requests(params, m, amax, sample,
-                           spec["cell"]["engine"]["max_len"], ctrl)
-    del params
-    return got
+    return spec["arch"].score(spec["config"]["model"], seed, sample,
+                              spec["cell"]["engine"]["max_len"], control)
+
+
+def layer_record(spec, run_: dict, extra: dict, peaks) -> dict:
+    """What the per-layer readers read: the run's counters and trace, and
+    the work the window's forwards needed (the architecture's count)."""
+    w0, w1 = run_["w0"], run_["w1"]
+    forwards = [f for t, f in run_["drv"].forwards if w0 < t <= w1]
+    m = spec["config"]["model"]
+    return {"cell": spec["cell"], "model": m,
+            "slots": spec["cell"]["engine"]["slots"],
+            "counters": run_["counters"], "trace": run_["trace"],
+            "peaks": peaks, "window_s": w1 - w0,
+            "work": spec["arch"].window_work(m, forwards, peaks),
+            "queue_wait_ms": extra["queue_wait_ms"],
+            "ttft_ms": extra["ttft_ms"]}
 
 
 def run(spec: dict, seed: int, seconds: float, trace: bool, devices,
         fault=None) -> dict:
     from chipbench import peaks as P
-    from chipbench import work as W
 
     cell = spec["cell"]
     run_ = measure(spec, seed, seconds, trace, devices, fault)
@@ -495,13 +506,7 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, devices,
         f"{drv.chunk_mismatch}")
     kind = devices[0].device_kind
     peaks = P.peaks_for(kind) if devices[0].platform == "tpu" else P.V5E
-    forwards = [f for t, f in drv.forwards if w0 < t <= w1]
-    rec = {"cell": cell, "model": spec["config"]["model"],
-           "slots": cell["engine"]["slots"], "counters": run_["counters"],
-           "trace": run_["trace"], "peaks": peaks, "window_s": w1 - w0,
-           "work": W.window_work(spec["config"]["model"], forwards, peaks),
-           "queue_wait_ms": extra["queue_wait_ms"],
-           "ttft_ms": extra["ttft_ms"]}
+    rec = layer_record(spec, run_, extra, peaks)
     metrics = {}
     if trace:
         for m in spec["per_layer"]:

@@ -334,7 +334,6 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(HERE))
     import run as RUN
     from chipbench import peaks as P
-    from chipbench import work as W
 
     spec = RUN.load_spec(args.workload)
     devs = jax.devices()
@@ -359,14 +358,8 @@ def main(argv=None) -> int:
     drv, tr = run_["drv"], run_["trace"]
     w0, w1 = run_["w0"], run_["w1"]
     forwards = [f for t, f in drv.forwards if w0 < t <= w1]
-    peaks = P.peaks_for(devs[0].device_kind)
-    rec = {"cell": spec["cell"], "model": spec["config"]["model"],
-           "slots": spec["cell"]["engine"]["slots"],
-           "counters": run_["counters"], "trace": tr, "peaks": peaks,
-           "window_s": w1 - w0,
-           "work": W.window_work(spec["config"]["model"], forwards, peaks),
-           "queue_wait_ms": extra["queue_wait_ms"],
-           "ttft_ms": extra["ttft_ms"]}
+    rec = RUN.layer_record(spec, run_, extra,
+                           P.peaks_for(devs[0].device_kind))
     names = [m["name"] for m in spec["per_layer"]] + list(TICK_METRICS)
     metrics = {n: RUN.load_reader(n)(rec) for n in names}
     print(json.dumps({

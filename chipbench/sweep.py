@@ -51,7 +51,7 @@ def main(argv=None) -> int:
     configure_compile_cache()
     clock = time.perf_counter
     m = spec["config"]["model"]
-    eng, _ = RUN.build_engine(spec, args.seed, jax.devices()[:1], clock)
+    eng, _ = RUN.build_engine(spec, args.seed, jax.devices()[:1])
     RUN.warm(eng, spec, m["vocab_size"], clock, args.seed)
     drv = RUN.Driver(eng, spec["cell"]["engine"]["chunk"],
                      lambda _name: contextlib.nullcontext())

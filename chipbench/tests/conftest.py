@@ -18,10 +18,14 @@ E2E = [{"name": n, "unit": u} for n, u in
 
 
 def smoke_spec(mix: str) -> dict:
+    from chipbench import run as RUN
+
     data = HERE / "data"
+    config = json.loads((data / "smoke.config.json").read_text())
     return {
         "workload": {"name": f"smoke.{mix}", "chips": 1},
-        "config": json.loads((data / "smoke.config.json").read_text()),
+        "config": config,
+        "arch": RUN.load_arch(config, data / "smoke.config.json"),
         "mix": json.loads((data / f"smoke.{mix}.json").read_text()),
         "cell": json.loads((data / "smoke.cell.json").read_text()),
         "end_to_end": E2E,
